@@ -35,16 +35,21 @@ import jax.numpy as jnp
 from repro.distributed.decentralized import init_dist_state, make_dist_train_step
 from repro.distributed.gossip import as_schedule, make_gossip_plan
 from repro.distributed.wire import IdentityWire, make_wire_format
+from repro.launch.mesh import node_mesh
 from repro.optim import sgd
 from repro.optim.schedules import constant
 
-# The fused Pallas decode kernels; jaxpr text carries their names.
+# The fused Pallas decode kernels, by the ``name=`` their pallas_call carries
+# (kernels/quant.py, kernels/lowrank.py).  A jaxpr prints it as
+# ``name=<kernel>``, and a TPU profile names the kernel after it.
 DECODE_KERNELS = (
-    "_unpack_dequant_axpy_kernel",
-    "_sparse_scatter_axpy_kernel",
-    "_unpack_sign_axpy_kernel",
-    "_lowrank_axpy_kernel",
+    "unpack_dequant_axpy",
+    "sparse_scatter_axpy",
+    "unpack_sign_axpy",
+    "lowrank_axpy",
 )
+
+_NAME_PARAM = re.compile(r"\bname=(\w+)")
 
 _CALLBACK_PRIMS = ("pure_callback", "io_callback", "debug_callback",
                    "host_callback")
@@ -85,8 +90,9 @@ def permute_operands(hlo_text: str) -> List[PermuteOperand]:
 
 
 def kernel_call_counts(jaxpr_text: str) -> Dict[str, int]:
-    """Occurrences of each fused decode kernel name in a jaxpr dump."""
-    return {k: jaxpr_text.count(k) for k in DECODE_KERNELS}
+    """pallas_call equations of each fused decode kernel in a jaxpr dump."""
+    names = _NAME_PARAM.findall(jaxpr_text)
+    return {k: names.count(k) for k in DECODE_KERNELS}
 
 
 def check_no_f64(text: str) -> List[str]:
@@ -279,7 +285,7 @@ def analyze_case(algo: str, topology: str, wire_spec: Optional[str],
     (algo, topology, wire, drop) config and run every invariant check."""
     sched = make_gossip_plan(topology, n)
     wire = make_wire_format(wire_spec) if wire_spec else None
-    mesh = jax.make_mesh((n,), ("node",)) if hlo else None
+    mesh = node_mesh(jax.devices()[:n]) if hlo else None
     step = make_dist_train_step(
         _toy_loss, algo, sgd(), wire, sched, constant(0.05),
         mesh=mesh, drop=drop or None)

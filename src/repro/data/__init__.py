@@ -1,1 +1,1 @@
-from repro.data.pipeline import DataConfig, iterate, sample_batch, stacked_node_batches
+from repro.data.pipeline import DataConfig, sample_batch, stacked_node_batches

@@ -11,19 +11,27 @@ gives every node a *different, deterministic* token stream:
 * fully deterministic in (seed, step, node) — restart-safe for checkpoint resume,
   and the same batch is reproducible on any topology.
 
+The transition logits are low rank, ``E[tok] @ F.T``, computed one row per
+walker and step, so no vocab x vocab table exists at a published vocabulary
+(50,280 entries would make it 10 GB).  A step's batch for every node comes
+out of one jitted call.
+
 For VLM/audio archs the pipeline also emits synthetic frontend embeddings
 (the modality encoders are stubs per the assignment).
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Iterator, Optional
+import functools
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.configs.base import ArchConfig
+
+# Rank of the synthetic language's transition logits.
+_MARKOV_RANK = 64
 
 
 @dataclasses.dataclass(frozen=True)
@@ -36,30 +44,29 @@ class DataConfig:
     markov_concentration: float = 0.3   # smaller = more structure (lower entropy)
 
 
-def _markov_logits(key: jax.Array, vocab: int, concentration: float) -> jax.Array:
-    """Fixed random transition logits defining the synthetic language."""
-    return jax.random.normal(key, (vocab, vocab)) / concentration
+def _markov_factors(cfg: DataConfig):
+    """Fixed random factors of the transition logits, scaled so each logit is
+    ~ N(0, 1/concentration²) like a dense Gaussian table's."""
+    ke, kf = jax.random.split(jax.random.key(cfg.seed + 7919))
+    e = jax.random.normal(ke, (cfg.vocab, _MARKOV_RANK))
+    f = jax.random.normal(kf, (cfg.vocab, _MARKOV_RANK))
+    return e / (cfg.markov_concentration * _MARKOV_RANK ** 0.5), f
 
 
-def sample_batch(cfg: DataConfig, step: int, shard: int, arch: Optional[ArchConfig] = None
-                 ) -> Dict[str, jax.Array]:
-    """Deterministic batch for (step, shard): tokens, labels (next-token), extras."""
-    assert 0 <= shard < cfg.n_shards
+def _shard_batch(cfg: DataConfig, arch: Optional[ArchConfig], factors,
+                 step: jax.Array, shard: jax.Array) -> Dict[str, jax.Array]:
     per_shard = cfg.global_batch // cfg.n_shards
     key = jax.random.fold_in(jax.random.fold_in(jax.random.key(cfg.seed), step), shard)
     k_init, k_walk, k_extra = jax.random.split(key, 3)
-
-    trans = _markov_logits(jax.random.key(cfg.seed + 7919), cfg.vocab, cfg.markov_concentration)
-    n_front = 0
+    e, f = factors
     s_text = cfg.seq_len
     if arch is not None and arch.frontend is not None and arch.frontend.kind == "vision":
-        n_front = arch.frontend.n_tokens
-        s_text = cfg.seq_len - n_front
+        s_text = cfg.seq_len - arch.frontend.n_tokens
 
     x0 = jax.random.randint(k_init, (per_shard,), 0, cfg.vocab)
 
     def walk(tok, k):
-        nxt = jax.random.categorical(k, trans[tok])
+        nxt = jax.random.categorical(k, e[tok] @ f.T)
         return nxt, nxt
 
     keys = jax.random.split(k_walk, s_text)
@@ -72,16 +79,24 @@ def sample_batch(cfg: DataConfig, step: int, shard: int, arch: Optional[ArchConf
     return batch
 
 
-def iterate(cfg: DataConfig, shard: int, arch: Optional[ArchConfig] = None,
-            start_step: int = 0) -> Iterator[Dict[str, jax.Array]]:
-    step = start_step
-    while True:
-        yield sample_batch(cfg, step, shard, arch)
-        step += 1
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _node_batches(cfg: DataConfig, arch: Optional[ArchConfig], step: jax.Array,
+                  shards: jax.Array) -> Dict[str, jax.Array]:
+    factors = _markov_factors(cfg)
+    return jax.vmap(lambda s: _shard_batch(cfg, arch, factors, step, s))(shards)
+
+
+def sample_batch(cfg: DataConfig, step: int, shard: int, arch: Optional[ArchConfig] = None
+                 ) -> Dict[str, jax.Array]:
+    """Deterministic batch for (step, shard): tokens, labels (next-token), extras."""
+    assert 0 <= shard < cfg.n_shards
+    batch = _node_batches(cfg, arch, jnp.asarray(step, jnp.int32),
+                          jnp.asarray([shard], jnp.int32))
+    return jax.tree.map(lambda l: l[0], batch)
 
 
 def stacked_node_batches(cfg: DataConfig, step: int, arch: Optional[ArchConfig] = None
                          ) -> Dict[str, jax.Array]:
-    """All shards stacked on a leading node axis — feeds the stacked simulator."""
-    batches = [sample_batch(cfg, step, s, arch) for s in range(cfg.n_shards)]
-    return jax.tree.map(lambda *ls: jnp.stack(ls), *batches)
+    """All shards stacked on a leading node axis, from one jitted call."""
+    return _node_batches(cfg, arch, jnp.asarray(step, jnp.int32),
+                         jnp.arange(cfg.n_shards, dtype=jnp.int32))

@@ -49,7 +49,6 @@ the compiled step, and identical to the stacked reference's seeding.
 from __future__ import annotations
 
 import functools
-import os
 import warnings
 from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
@@ -228,34 +227,25 @@ def _make_decode_axpy(wire: WireFormat, mesh) -> Optional[Callable]:
     Returns ``None`` for meshes with axes beyond "node": wrapping only the
     node axis would force GSPMD to gather every fsdp/model-sharded leaf at the
     shard_map boundary (the §Perf-iteration-3 regression this runtime exists
-    to avoid), and shard_map's ``auto`` escape hatch for the remaining axes
-    check-fails inside XLA's SPMD partitioner on the current pin — the caller
-    then keeps the sharding-preserving jnp reference path.  Setting
-    ``REPRO_SHARD_MAP_AUTO=1`` opts the multi-axis case into the ``auto``
-    path anyway — the CI ``jax-nightly`` probe (tests/probe_shard_map_auto.py)
-    uses it to re-test the check-fail on newer XLA pins (ROADMAP item).
+    to avoid); the caller then keeps the sharding-preserving jnp reference
+    path.  Partial-manual ``shard_map(axis_names={"node"})`` is what would
+    lift this.
     """
     if mesh is None or "node" not in getattr(mesh, "axis_names", ()):
         return wire.decode_axpy_tree
-    nonnode = frozenset(a for a in mesh.axis_names if a != "node")
-    auto_opt_in = os.environ.get("REPRO_SHARD_MAP_AUTO", "").lower() \
-        not in ("", "0", "false")
-    if nonnode and not auto_opt_in:
+    if any(a != "node" for a in mesh.axis_names):
         return None
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
-
-    kwargs = {"auto": nonnode} if nonnode else {}
 
     def dec_axpy(treedef, payloads, acc_tree, weight, acc_weight=1.0):
         def inner(payloads_, acc_, w_, aw_):
             return wire.decode_axpy_tree(treedef, payloads_, acc_, w_, aw_)
 
-        return shard_map(
-            inner, mesh,
+        return jax.shard_map(
+            inner, mesh=mesh,
             in_specs=(P("node"), P("node"), P(), P()),
-            out_specs=P("node"), check_rep=False, **kwargs,
+            out_specs=P("node"), check_vma=False,
         )(payloads, acc_tree, jnp.asarray(weight, jnp.float32),
           jnp.asarray(acc_weight, jnp.float32))
 

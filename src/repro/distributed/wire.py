@@ -57,6 +57,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import ops as _kops
 from repro.kernels.ops import payload_nbytes as _payload_nbytes
 from repro.kernels.quant import (
     pcg_hash,
@@ -66,6 +67,7 @@ from repro.kernels.quant import (
     unpack_sign_axpy_2d,
 )
 from repro.kernels.ref import (
+    F32_MATMUL,
     SIGN_SCALE_MODES,
     SPARSE_MODES,
     aligned_block,
@@ -428,7 +430,7 @@ def _fused_axpy_leaf(codes: jax.Array, scale: jax.Array, acc: jax.Array, *,
         scale.reshape(rows, 1),
         accf.reshape(rows, block),
         bits=bits, weight=weight, acc_weight=acc_weight,
-        interpret=jax.default_backend() != "tpu")
+        interpret=_kops.interpret_kernels())
     out = out.reshape(*lead, nblk * block)[..., :orig_last]
     return out.astype(acc.dtype)
 
@@ -527,7 +529,7 @@ def _fused_sparse_axpy_leaf(values: jax.Array, packed_idx: jax.Array,
         packed_idx.reshape(rows, packed_idx.shape[-1]),
         accf.reshape(rows, block),
         weight=weight, acc_weight=acc_weight,
-        interpret=jax.default_backend() != "tpu")
+        interpret=_kops.interpret_kernels())
     out = out.reshape(*lead, nblk * block)[..., :orig_last]
     return out.astype(acc.dtype)
 
@@ -651,7 +653,7 @@ def _fused_sign_axpy_leaf(codes: jax.Array, scale: jax.Array, acc: jax.Array,
         scale.reshape(rows, 1),
         accf.reshape(rows, block),
         weight=weight, acc_weight=acc_weight,
-        interpret=jax.default_backend() != "tpu")
+        interpret=_kops.interpret_kernels())
     out = out.reshape(*lead, nblk * block)[..., :orig_last]
     return out.astype(acc.dtype)
 
@@ -700,7 +702,7 @@ def _batch_dot(a: jax.Array, b: jax.Array, a_dim: int, b_dim: int) -> jax.Array:
     return jax.lax.dot_general(
         a.astype(jnp.float32), b.astype(jnp.float32),
         (((a.ndim + a_dim,), (b.ndim + b_dim,)), (lead, lead)),
-        preferred_element_type=jnp.float32)
+        precision=F32_MATMUL, preferred_element_type=jnp.float32)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -807,7 +809,7 @@ class LowRankWire(WireFormat):
         if v0.ndim == 2:
             p = jax.lax.dot_general(
                 m, v0, (((m.ndim - 1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+                precision=F32_MATMUL, preferred_element_type=jnp.float32)
         else:
             p = _batch_dot(m, v0, -1, -2)
         p = lowrank_orthonormalize_ref(p)
@@ -940,7 +942,7 @@ def _fused_lowrank_axpy_leaf(p: jax.Array, v: jax.Array, acc: jax.Array, *,
     b = int(np.prod(lead, dtype=np.int64)) if lead else 1
     fn = functools.partial(lowrank_axpy_2d, weight=weight,
                            acc_weight=acc_weight,
-                           interpret=jax.default_backend() != "tpu")
+                           interpret=_kops.interpret_kernels())
     out = jax.vmap(fn)(p.reshape(b, mm, r), v.reshape(b, nn, r),
                        acc.astype(jnp.float32).reshape(b, mm, nn))
     return out.reshape(*lead, mm, nn).astype(acc.dtype)

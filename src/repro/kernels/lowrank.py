@@ -14,8 +14,9 @@ Two kernels, one per wire direction:
   same (2,) scalar operand as the quantized/sparse/sign axpy kernels, so
   traced mixing weights drive this kernel too.
 
-Bit-identity contract (vs kernels/ref.py): the grid tiles ONLY the output
-rows — the n-contraction is never split — and each tile issues a single
+Bit-identity contract (vs kernels/ref.py): the grids tile only output rows
+and, in the axpy kernel, output columns — the r- and n-contractions are never
+split — and each tile issues a single
 ``dot_general`` with ``preferred_element_type=f32`` using the exact dimension
 numbers of the oracle (``_factor_matmul`` is literally shared).  Every output
 element therefore reduces over the same operands in the same order as the
@@ -33,9 +34,8 @@ paths; rank-1 still holds to 1 ulp, well inside the differential tier's
 tolerance.
 
 TPU note: the rank axis (r = 2..8 typically) is far below the 128-lane tile,
-so on real silicon Mosaic pads the (bm, r) factor tiles — wasteful but
-correct; CI runs interpret mode where the point is moot.  TPU-silicon lane
-utilization of the factor tiles rides the existing ROADMAP validation item.
+so Mosaic pads the (bm, r) and (bn, r) factor tiles to 128 lanes; the block
+pickers count that padding against the VMEM budget.
 """
 from __future__ import annotations
 
@@ -43,8 +43,22 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.quant import _pad_rows, _pick_block_rows
-from repro.kernels.ref import _factor_matmul
+from repro.kernels.quant import _pad_rows, _padded_row_bytes, _pick_block_rows
+from repro.kernels.ref import F32_MATMUL, _factor_matmul
+
+# Widest output column tile of the axpy kernel.  The (bn, r) right factor
+# pads to 128 lanes in VMEM, so a whole vocabulary-wide factor would not fit.
+_MAX_BLOCK_COLS = 2048
+
+
+def _pick_block_cols(n: int) -> int:
+    """Largest multiple of 128 that divides ``n`` and is at most
+    ``_MAX_BLOCK_COLS``; ``n`` itself when it is not lane-aligned (the
+    interpret-mode tests' small leaves)."""
+    if n % 128:
+        return n
+    return max(c for c in range(128, min(n, _MAX_BLOCK_COLS) + 1, 128)
+               if n % c == 0)
 
 
 def _lowrank_project_kernel(m_ref, v_ref, out_ref):
@@ -52,7 +66,7 @@ def _lowrank_project_kernel(m_ref, v_ref, out_ref):
     # one dot per tile, contraction unsplit => oracle-exact.
     out_ref[...] = jax.lax.dot_general(
         m_ref[...], v_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=F32_MATMUL, preferred_element_type=jnp.float32)
 
 
 def _lowrank_axpy_kernel(weights_ref, p_ref, v_ref, acc_ref, out_ref):
@@ -72,11 +86,14 @@ def lowrank_project_2d(m: jax.Array, v: jax.Array, *,
     rows, n = m.shape
     n2, r = v.shape
     assert n == n2, (m.shape, v.shape)
-    bm = _pick_block_rows(rows, n)
+    bm = _pick_block_rows(rows, [(n, jnp.float32), (r, jnp.float32)],
+                          temps=[r],
+                          fixed_bytes=2 * n2 * _padded_row_bytes(r, jnp.float32))
     (m,), pad = _pad_rows([m], bm, rows)
     grid = ((rows + pad) // bm,)
     out = pl.pallas_call(
         _lowrank_project_kernel,
+        name="lowrank_project",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, n), lambda i: (i, 0)),
@@ -103,21 +120,25 @@ def lowrank_axpy_2d(p: jax.Array, v: jax.Array, acc: jax.Array, *,
     n, r2 = v.shape
     assert r == r2, (p.shape, v.shape)
     assert acc.shape == (rows, n), (acc.shape, (rows, n))
-    bm = _pick_block_rows(rows, n)
+    bn = _pick_block_cols(n)
+    bm = _pick_block_rows(rows, [(r, jnp.float32), (bn, jnp.float32),
+                                 (bn, jnp.float32)], temps=[bn],
+                          fixed_bytes=2 * bn * _padded_row_bytes(r, jnp.float32))
     (p, acc), pad = _pad_rows([p, acc], bm, rows)
-    grid = ((rows + pad) // bm,)
+    grid = ((rows + pad) // bm, n // bn)
     weights = jnp.stack([jnp.asarray(acc_weight, jnp.float32).reshape(()),
                          jnp.asarray(weight, jnp.float32).reshape(())])
     out = pl.pallas_call(
         _lowrank_axpy_kernel,
+        name="lowrank_axpy",
         grid=grid,
         in_specs=[
-            pl.BlockSpec((2,), lambda i: (0,)),
-            pl.BlockSpec((bm, r), lambda i: (i, 0)),
-            pl.BlockSpec((n, r2), lambda i: (0, 0)),
-            pl.BlockSpec((bm, n), lambda i: (i, 0)),
+            pl.BlockSpec((2,), lambda i, j: (0,)),
+            pl.BlockSpec((bm, r), lambda i, j: (i, 0)),
+            pl.BlockSpec((bn, r2), lambda i, j: (j, 0)),
+            pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         ],
-        out_specs=pl.BlockSpec((bm, n), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((rows + pad, n), jnp.float32),
         interpret=interpret,
     )(weights, p.astype(jnp.float32), v.astype(jnp.float32),

@@ -1,8 +1,9 @@
 """Jitted public wrappers around the Pallas kernels.
 
 Handles: arbitrary input shapes (flatten/pad to the 2-D blocked view), PRNG-key ->
-seed derivation, interpret-mode fallback on non-TPU backends, and payloads in the
-same wire format as :class:`repro.core.compression.RandomQuantizer`:
+seed derivation, interpret mode on the CPU backend (:func:`interpret_kernels`),
+and payloads in the same wire format as
+:class:`repro.core.compression.RandomQuantizer`:
 
 * ``bits=8``: ``codes`` int8 ``(n_blocks, block_size)`` + ``scale`` f32
   ``(n_blocks, 1)``.
@@ -32,8 +33,22 @@ import numpy as np
 from repro.kernels import quant as _q
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def interpret_kernels() -> bool:
+    """Whether the Pallas kernels run in interpret mode — the one place the
+    repository decides it.
+
+    On a TPU they compile with Mosaic; on the CPU backend, where the tests run,
+    they are interpreted.  Any other backend is an error, never a silent
+    fallback.  Code that compiles for a described TPU from a CPU process
+    (``jax.experimental.topologies``) patches this function to return False.
+    """
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"the Pallas kernels run on a TPU, or interpreted on "
+                       f"the CPU backend; this process's backend is {backend!r}")
 
 
 def _to_blocks(x: jax.Array, block_size: int) -> jax.Array:
@@ -67,9 +82,9 @@ def quantize(key: jax.Array, x: jax.Array, *, bits: int = 8, block_size: int = 1
     seed = jax.random.bits(key, (1,), dtype=jnp.uint32)
     blocks = _to_blocks(x, block_size)
     if packed:
-        codes, scale = _q.quantize_pack_2d(blocks, seed, bits=bits, interpret=_interpret())
+        codes, scale = _q.quantize_pack_2d(blocks, seed, bits=bits, interpret=interpret_kernels())
     else:
-        codes, scale = _q.quantize_2d(blocks, seed, bits=bits, interpret=_interpret())
+        codes, scale = _q.quantize_2d(blocks, seed, bits=bits, interpret=interpret_kernels())
     return {"codes": codes, "scale": scale}
 
 
@@ -77,10 +92,10 @@ def quantize(key: jax.Array, x: jax.Array, *, bits: int = 8, block_size: int = 1
 def dequantize(payload: dict, *, bits: int = 8, shape: tuple = (), dtype: Any = jnp.float32) -> jax.Array:
     if payload["codes"].dtype == jnp.uint32:
         out = _q.unpack_dequant_2d(payload["codes"], payload["scale"], bits=bits,
-                                   interpret=_interpret())
+                                   interpret=interpret_kernels())
     else:
         out = _q.dequantize_2d(payload["codes"], payload["scale"], bits=bits,
-                               interpret=_interpret())
+                               interpret=interpret_kernels())
     n = int(np.prod(shape)) if shape else 1
     return out.reshape(-1)[:n].reshape(shape).astype(dtype)
 
@@ -102,7 +117,7 @@ def sparse_compress(key: jax.Array, x: jax.Array, *, p: float = 0.25,
     blocks = _to_blocks(x, block_size)
     vals, idx = _q.sparse_select_pack_2d(blocks, seed, p=p, mode=mode,
                                          value_dtype=value_dtype,
-                                         interpret=_interpret())
+                                         interpret=interpret_kernels())
     return {"values": vals, "idx": idx}
 
 
@@ -110,7 +125,7 @@ def sparse_compress(key: jax.Array, x: jax.Array, *, p: float = 0.25,
 def sparse_decompress(payload: dict, *, block_size: int = 128, shape: tuple = (),
                       dtype: Any = jnp.float32) -> jax.Array:
     out = _q.sparse_unpack_scatter_2d(payload["values"], payload["idx"],
-                                      cols=block_size, interpret=_interpret())
+                                      cols=block_size, interpret=interpret_kernels())
     n = int(np.prod(shape)) if shape else 1
     return out.reshape(-1)[:n].reshape(shape).astype(dtype)
 
@@ -125,7 +140,7 @@ def sparse_axpy(payload: dict, acc: jax.Array, *, block_size: int,
     """
     blocks = _to_blocks(acc, block_size)
     out = _q.sparse_scatter_axpy_2d(payload["values"], payload["idx"], blocks,
-                                    weight=weight, interpret=_interpret())
+                                    weight=weight, interpret=interpret_kernels())
     n = acc.size
     return out.reshape(-1)[:n].reshape(acc.shape).astype(acc.dtype)
 
@@ -144,9 +159,9 @@ def dequant_axpy(payload: dict, acc: jax.Array, *, bits: int, weight: float) -> 
     blocks = _to_blocks(acc, block_size)
     if packed:
         out = _q.unpack_dequant_axpy_2d(payload["codes"], payload["scale"], blocks,
-                                        bits=bits, weight=weight, interpret=_interpret())
+                                        bits=bits, weight=weight, interpret=interpret_kernels())
     else:
         out = blocks + weight * _q.dequantize_2d(payload["codes"], payload["scale"],
-                                                 bits=bits, interpret=_interpret())
+                                                 bits=bits, interpret=interpret_kernels())
     n = acc.size
     return out.reshape(-1)[:n].reshape(acc.shape).astype(acc.dtype)

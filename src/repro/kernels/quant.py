@@ -140,7 +140,9 @@ def pcg_hash(x: jax.Array) -> jax.Array:
 def uniform_from_hash(idx: jax.Array, seed: jax.Array) -> jax.Array:
     """Deterministic U[0,1) from a per-element counter and a scalar seed."""
     bits = pcg_hash(idx ^ seed.astype(jnp.uint32))
-    return (bits >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
+    # 24-bit value, exact in int32 and f32; Mosaic has no uint32 -> f32 cast
+    return (bits >> jnp.uint32(8)).astype(jnp.int32).astype(jnp.float32) \
+        * jnp.float32(1.0 / (1 << 24))
 
 
 def _stochastic_codes(x, seed_ref, pid, *, levels: int, block_rows: int, cols: int):
@@ -175,7 +177,8 @@ def _quant_pack_kernel(seed_ref, x_ref, packed_ref, scale_ref, *,
     x = x_ref[...].astype(jnp.float32)
     q, scale = _stochastic_codes(x, seed_ref, pl.program_id(0),
                                  levels=levels, block_rows=block_rows, cols=cols)
-    u = (q + jnp.float32(levels + 1)).astype(jnp.uint32)   # biased, in [1, 2^bits-1]
+    # biased, in [1, 2^bits-1]; via int32 (Mosaic has no f32 -> uint32 cast)
+    u = (q + jnp.float32(levels + 1)).astype(jnp.int32).astype(jnp.uint32)
     cpg, wpg = stream_geometry(bits)
     g = cols // cpg
     words = [jnp.zeros(u.shape[:-1] + (g,), jnp.uint32) for _ in range(wpg)]
@@ -236,11 +239,40 @@ def _unpack_dequant_axpy_kernel(weights_ref, packed_ref, scale_ref, acc_ref, out
             aw * acc_ref[:, j * g:(j + 1) * g] + u.astype(jnp.float32) * inv)
 
 
-def _pick_block_rows(rows: int, cols: int, vmem_budget: int = 4 << 20) -> int:
-    bm = max(8, vmem_budget // (4 * cols))
-    bm = min(bm, rows)
-    # round to a multiple of 8 (f32 sublane) without exceeding rows
-    return max(8, (bm // 8) * 8) if rows >= 8 else rows
+# v5e's default scoped-VMEM limit is 16 MiB per kernel.  Half of it goes to
+# the pipelined tiles and the body's temporaries as counted below; the rest is
+# headroom for what Mosaic allocates on its own (spilled vregs, masks).
+VMEM_TILE_BUDGET = 8 << 20
+
+
+def _padded_row_bytes(cols: int, dtype) -> int:
+    """VMEM bytes of one row of a tile: lanes pad to a multiple of 128."""
+    return -(-cols // 128) * 128 * jnp.dtype(dtype).itemsize
+
+
+def _pick_block_rows(rows: int, tiles, *, temps=(), fixed_bytes: int = 0,
+                     budget: int = VMEM_TILE_BUDGET) -> int:
+    """Row tile ``bm`` (a multiple of 8) whose VMEM footprint fits ``budget``.
+
+    ``tiles``: ``(cols, dtype)`` of every row-tiled operand, inputs and
+    outputs; each is double-buffered by the pipeline and padded to 128 lanes,
+    so a ``(bm, 1)`` scale costs as much as a ``(bm, 128)`` one.  ``temps``:
+    the ``cols`` of each f32 ``(bm, cols)`` value the kernel body keeps live
+    beside them.  ``fixed_bytes``: untiled operands (whole factors).  Among
+    the fitting sizes a divisor of ``rows`` is preferred when one is at least
+    half the largest, so the caller need not pad (copy) its operands.  The
+    codes do not depend on ``bm``: every PCG counter is global.
+    """
+    if rows < 8:
+        return rows
+    per_row = 2 * sum(_padded_row_bytes(c, d) for c, d in tiles) \
+        + sum(_padded_row_bytes(c, jnp.float32) for c in temps)
+    bm = max(8, (budget - fixed_bytes) // per_row // 8 * 8)
+    bm = min(bm, rows // 8 * 8)
+    for cand in range(bm, bm // 2 - 1, -8):
+        if rows % cand == 0:
+            return cand
+    return bm
 
 
 def _pad_rows(arrs, bm: int, rows: int):
@@ -255,12 +287,14 @@ def quantize_2d(x: jax.Array, seed: jax.Array, *, bits: int, interpret: bool = F
     rows, cols = x.shape
     assert cols % 128 == 0, f"block_size must be a multiple of 128, got {cols}"
     levels = 2 ** (bits - 1) - 1
-    bm = _pick_block_rows(rows, cols)
+    bm = _pick_block_rows(rows, [(cols, jnp.float32), (cols, jnp.int8),
+                                 (1, jnp.float32)], temps=[cols] * 4)
     (x,), pad = _pad_rows([x], bm, rows)
     grid = ((rows + pad) // bm,)
     kernel = functools.partial(_quant_kernel, levels=levels, block_rows=bm, cols=cols)
     codes, scale = pl.pallas_call(
         kernel,
+        name="quantize",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1,), lambda i: (0,)),  # scalar seed, broadcast to all programs
@@ -293,13 +327,15 @@ def quantize_pack_2d(x: jax.Array, seed: jax.Array, *, bits: int, interpret: boo
     assert cols % 128 == 0, f"block_size must be a multiple of 128, got {cols}"
     levels = 2 ** (bits - 1) - 1
     w = cols * bits // 32
-    bm = _pick_block_rows(rows, cols)
+    bm = _pick_block_rows(rows, [(cols, jnp.float32), (w, jnp.uint32),
+                                 (1, jnp.float32)], temps=[cols] * 4)
     (x,), pad = _pad_rows([x], bm, rows)
     grid = ((rows + pad) // bm,)
     kernel = functools.partial(_quant_pack_kernel, bits=bits, levels=levels,
                                block_rows=bm, cols=cols)
     packed, scale = pl.pallas_call(
         kernel,
+        name="quantize_pack",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1,), lambda i: (0,)),
@@ -323,11 +359,13 @@ def quantize_pack_2d(x: jax.Array, seed: jax.Array, *, bits: int, interpret: boo
 def dequantize_2d(codes: jax.Array, scale: jax.Array, *, bits: int, interpret: bool = False) -> jax.Array:
     rows, cols = codes.shape
     levels = 2 ** (bits - 1) - 1
-    bm = _pick_block_rows(rows, cols)
+    bm = _pick_block_rows(rows, [(cols, codes.dtype), (1, jnp.float32),
+                                 (cols, jnp.float32)], temps=[cols])
     (codes, scale), pad = _pad_rows([codes, scale], bm, rows)
     grid = ((rows + pad) // bm,)
     out = pl.pallas_call(
         functools.partial(_dequant_kernel, levels=levels),
+        name="dequantize",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, cols), lambda i: (i, 0)),
@@ -347,11 +385,13 @@ def unpack_dequant_2d(packed: jax.Array, scale: jax.Array, *, bits: int,
     assert bits in PACKABLE_BITS
     levels = 2 ** (bits - 1) - 1
     cols = w * 32 // bits
-    bm = _pick_block_rows(rows, cols)
+    bm = _pick_block_rows(rows, [(w, jnp.uint32), (1, jnp.float32),
+                                 (cols, jnp.float32)], temps=[w])
     (packed, scale), pad = _pad_rows([packed, scale], bm, rows)
     grid = ((rows + pad) // bm,)
     out = pl.pallas_call(
         functools.partial(_unpack_dequant_kernel, bits=bits, levels=levels),
+        name="unpack_dequant",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, w), lambda i: (i, 0)),
@@ -382,13 +422,16 @@ def unpack_dequant_axpy_2d(packed: jax.Array, scale: jax.Array, acc: jax.Array, 
     levels = 2 ** (bits - 1) - 1
     cols = w * 32 // bits
     assert acc.shape == (rows, cols), (acc.shape, (rows, cols))
-    bm = _pick_block_rows(rows, cols)
+    bm = _pick_block_rows(rows, [(w, jnp.uint32), (1, jnp.float32),
+                                 (cols, jnp.float32), (cols, jnp.float32)],
+                          temps=[w])
     (packed, scale, acc), pad = _pad_rows([packed, scale, acc], bm, rows)
     grid = ((rows + pad) // bm,)
     weights = jnp.stack([jnp.asarray(acc_weight, jnp.float32).reshape(()),
                          jnp.asarray(weight, jnp.float32).reshape(())])
     out = pl.pallas_call(
         functools.partial(_unpack_dequant_axpy_kernel, bits=bits, levels=levels),
+        name="unpack_dequant_axpy",
         grid=grid,
         in_specs=[
             pl.BlockSpec((2,), lambda i: (0,)),  # [acc_weight, weight], broadcast
@@ -416,12 +459,17 @@ def _sparse_select_pack_kernel(seed_ref, x_ref, vals_ref, idx_ref, *, mode: str,
     pack as the quantizer, over raw ``idx_bits``-wide unsigned fields.
     """
     x = x_ref[...].astype(jnp.float32)
-    lanes = jax.lax.broadcasted_iota(jnp.uint32, x.shape, 1)
+    # Mosaic reduces over signed ints and floats only: lanes and randk keys
+    # ride int32.  Flipping the top bit before the bitcast maps the uint32
+    # key order onto the int32 order, so the selection is unchanged.
+    lanes = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
     if mode == "randk":
         rows = jax.lax.broadcasted_iota(jnp.uint32, x.shape, 0) \
             + (pl.program_id(0) * block_rows).astype(jnp.uint32)
-        key = pcg_hash((rows * jnp.uint32(cols) + lanes) ^ seed_ref[0])
-        sentinel = jnp.uint32(0)
+        h = pcg_hash((rows * jnp.uint32(cols) + lanes.astype(jnp.uint32))
+                     ^ seed_ref[0])
+        key = jax.lax.bitcast_convert_type(h ^ jnp.uint32(0x80000000), jnp.int32)
+        sentinel = jnp.int32(-(1 << 31))   # == uint32 key 0
     else:
         mag = jnp.abs(x)
         # NaN ranks below every real magnitude but above masked-out lanes —
@@ -435,11 +483,11 @@ def _sparse_select_pack_kernel(seed_ref, x_ref, vals_ref, idx_ref, *, mode: str,
     for _ in range(k):
         masked = jnp.where(valid, key, sentinel)
         m = jnp.max(masked, axis=1, keepdims=True)
-        sel = jnp.min(jnp.where(valid & (masked == m), lanes, jnp.uint32(cols)),
+        sel = jnp.min(jnp.where(valid & (masked == m), lanes, jnp.int32(cols)),
                       axis=1, keepdims=True)
         hit = lanes == sel
         val_cols.append(jnp.sum(jnp.where(hit, x, 0.0), axis=1, keepdims=True))
-        sel_cols.append(sel)
+        sel_cols.append(sel.astype(jnp.uint32))
         valid = valid & ~hit
     vals = jnp.concatenate(val_cols, axis=1)
     if mode == "randk":
@@ -500,6 +548,13 @@ def _sparse_scatter_axpy_kernel(weights_ref, vals_ref, idx_ref, acc_ref, out_ref
     out_ref[...] = out
 
 
+def _scatter_temps(cols: int, k: int, idx_bits: int):
+    """f32-sized values the scatter kernels keep live: the dense accumulator,
+    the lane iota, and the ``cpg`` unpacked index fields of ``G`` lanes each."""
+    cpg, wpg = stream_geometry(idx_bits)
+    return [cols] * 2 + [max(1, -(-k // cpg))] * cpg
+
+
 def sparse_select_pack_2d(x: jax.Array, seed: jax.Array, *, p: float, mode: str,
                           value_dtype=jnp.float32, interpret: bool = False):
     """Fused fixed-capacity selection of a (rows, cols) f32 array.
@@ -513,7 +568,11 @@ def sparse_select_pack_2d(x: jax.Array, seed: jax.Array, *, p: float, mode: str,
     assert cols % 128 == 0, f"block_size must be a multiple of 128, got {cols}"
     assert mode in SPARSE_MODES, f"sparse modes are {SPARSE_MODES}, got {mode}"
     k, idx_bits, kpad, w_idx = sparse_geometry(cols, p)
-    bm = _pick_block_rows(rows, cols)
+    # the k selected (bm, 1) values and indices stay live until the final
+    # concatenate, each padded to a full lane tile
+    bm = _pick_block_rows(rows, [(cols, jnp.float32), (k, value_dtype),
+                                 (w_idx, jnp.uint32)],
+                          temps=[cols] * 4 + [1] * (2 * kpad))
     (x,), pad = _pad_rows([x], bm, rows)
     grid = ((rows + pad) // bm,)
     kernel = functools.partial(
@@ -521,6 +580,7 @@ def sparse_select_pack_2d(x: jax.Array, seed: jax.Array, *, p: float, mode: str,
         block_rows=bm, cols=cols, value_dtype=value_dtype)
     vals, idx = pl.pallas_call(
         kernel,
+        name="sparse_select_pack",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1,), lambda i: (0,)),
@@ -546,11 +606,14 @@ def sparse_unpack_scatter_2d(values: jax.Array, packed: jax.Array, *, cols: int,
     """Fused unpack + scatter: k values + packed index words -> (rows, cols) f32."""
     rows, k = values.shape
     idx_bits = idx_bits_for(cols)
-    bm = _pick_block_rows(rows, cols)
+    bm = _pick_block_rows(rows, [(k, values.dtype), (packed.shape[-1], jnp.uint32),
+                                 (cols, jnp.float32)],
+                          temps=_scatter_temps(cols, k, idx_bits))
     (values, packed), pad = _pad_rows([values, packed], bm, rows)
     grid = ((rows + pad) // bm,)
     out = pl.pallas_call(
         functools.partial(_sparse_scatter_kernel, k=k, idx_bits=idx_bits),
+        name="sparse_unpack_scatter",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, k), lambda i: (i, 0)),
@@ -604,7 +667,8 @@ def _unpack_sign_axpy_kernel(weights_ref, packed_ref, scale_ref, acc_ref,
     ws = scale_ref[...] * weights_ref[1]
     g = word.shape[-1]
     for j in range(32):
-        u = ((word >> jnp.uint32(j)) & jnp.uint32(1)).astype(jnp.float32)
+        u = ((word >> jnp.uint32(j)) & jnp.uint32(1)).astype(jnp.int32) \
+            .astype(jnp.float32)
         out_ref[:, j * g:(j + 1) * g] = (
             aw * acc_ref[:, j * g:(j + 1) * g] + (u * 2.0 - 1.0) * ws)
 
@@ -623,11 +687,13 @@ def sign_pack_2d(x: jax.Array, *, scale_mode: str = "mean",
     assert scale_mode in SIGN_SCALE_MODES, \
         f"sign scale modes are {SIGN_SCALE_MODES}, got {scale_mode}"
     w = cols // 32
-    bm = _pick_block_rows(rows, cols)
+    bm = _pick_block_rows(rows, [(cols, jnp.float32), (w, jnp.uint32),
+                                 (1, jnp.float32)], temps=[cols] * 2)
     (x,), pad = _pad_rows([x], bm, rows)
     grid = ((rows + pad) // bm,)
     packed, scale = pl.pallas_call(
         functools.partial(_sign_pack_kernel, scale_mode=scale_mode, cols=cols),
+        name="sign_pack",
         grid=grid,
         in_specs=[pl.BlockSpec((bm, cols), lambda i: (i, 0))],
         out_specs=[
@@ -660,13 +726,16 @@ def unpack_sign_axpy_2d(packed: jax.Array, scale: jax.Array, acc: jax.Array, *,
     rows, w = packed.shape
     cols = w * 32
     assert acc.shape == (rows, cols), (acc.shape, (rows, cols))
-    bm = _pick_block_rows(rows, cols)
+    bm = _pick_block_rows(rows, [(w, jnp.uint32), (1, jnp.float32),
+                                 (cols, jnp.float32), (cols, jnp.float32)],
+                          temps=[w])
     (packed, scale, acc), pad = _pad_rows([packed, scale, acc], bm, rows)
     grid = ((rows + pad) // bm,)
     weights = jnp.stack([jnp.asarray(acc_weight, jnp.float32).reshape(()),
                          jnp.asarray(weight, jnp.float32).reshape(())])
     out = pl.pallas_call(
         _unpack_sign_axpy_kernel,
+        name="unpack_sign_axpy",
         grid=grid,
         in_specs=[
             pl.BlockSpec((2,), lambda i: (0,)),
@@ -696,13 +765,16 @@ def sparse_scatter_axpy_2d(values: jax.Array, packed: jax.Array, acc: jax.Array,
     cols = acc.shape[-1]
     assert acc.shape == (rows, cols), (acc.shape, (rows, cols))
     idx_bits = idx_bits_for(cols)
-    bm = _pick_block_rows(rows, cols)
+    bm = _pick_block_rows(rows, [(k, values.dtype), (packed.shape[-1], jnp.uint32),
+                                 (cols, jnp.float32), (cols, jnp.float32)],
+                          temps=_scatter_temps(cols, k, idx_bits))
     (values, packed, acc), pad = _pad_rows([values, packed, acc], bm, rows)
     grid = ((rows + pad) // bm,)
     weights = jnp.stack([jnp.asarray(acc_weight, jnp.float32).reshape(()),
                          jnp.asarray(weight, jnp.float32).reshape(())])
     out = pl.pallas_call(
         functools.partial(_sparse_scatter_axpy_kernel, k=k, idx_bits=idx_bits),
+        name="sparse_scatter_axpy",
         grid=grid,
         in_specs=[
             pl.BlockSpec((2,), lambda i: (0,)),

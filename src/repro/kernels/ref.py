@@ -340,6 +340,12 @@ def lowrank_orthonormalize_ref(p: jax.Array, *, eps: float = 1e-8) -> jax.Array:
     return jnp.stack(cols, axis=-1)
 
 
+# Low-rank matmuls run at full f32 precision.  For f32 operands a TPU's
+# default is one bf16 pass, which would make the kernel, the codec and these
+# oracles disagree by bf16 rounding; the CPU backend computes f32 either way.
+F32_MATMUL = jax.lax.Precision.HIGHEST
+
+
 def _factor_matmul(a: jax.Array, b: jax.Array) -> jax.Array:
     """``a @ b`` contracting the LAST dim of both operands (i.e. ``a @ b.T``
     without materializing the transpose) in f32 accumulation.  Shared by the
@@ -348,7 +354,7 @@ def _factor_matmul(a: jax.Array, b: jax.Array) -> jax.Array:
     return jax.lax.dot_general(
         a.astype(jnp.float32), b.astype(jnp.float32),
         (((a.ndim - 1,), (b.ndim - 1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=F32_MATMUL, preferred_element_type=jnp.float32)
 
 
 def lowrank_project_2d_ref(m: jax.Array, v: jax.Array) -> jax.Array:
@@ -361,7 +367,7 @@ def lowrank_project_2d_ref(m: jax.Array, v: jax.Array) -> jax.Array:
     not atol."""
     return jax.lax.dot_general(
         m.astype(jnp.float32), v.astype(jnp.float32),
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        (((1,), (0,)), ((), ())), precision=F32_MATMUL, preferred_element_type=jnp.float32)
 
 
 def lowrank_axpy_2d_ref(p: jax.Array, v: jax.Array, acc: jax.Array, *,

@@ -1,6 +1,7 @@
 """Roofline-term extraction from compiled dry-run artifacts.
 
-Three terms per (arch x shape x mesh), in seconds (TPU v5e constants):
+Three terms per (arch x shape x mesh), in seconds, priced against a TPU v5e
+target model (published per-chip peaks, whatever device runs the dry-run):
 
     compute    = HLO_FLOPs_per_chip / peak_FLOPs           (197 TFLOP/s bf16)
     memory     = HLO_bytes_per_chip / HBM_bw               (819 GB/s)
@@ -18,9 +19,10 @@ import dataclasses
 import re
 from typing import Dict, Optional
 
-PEAK_FLOPS = 197e12         # bf16 / chip
-HBM_BW = 819e9              # bytes/s / chip
-LINK_BW = 50e9              # bytes/s / link (ICI); DCN is ~10-25x slower
+# The v5e target model the dry-run prices against — not the current device.
+V5E_PEAK_FLOPS = 197e12     # bf16 / chip
+V5E_HBM_BW = 819e9          # bytes/s / chip
+V5E_LINK_BW = 50e9          # bytes/s / link (ICI); DCN is ~10-25x slower
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -284,15 +286,15 @@ class Roofline:
 
     @property
     def t_compute(self) -> float:
-        return self.flops_per_chip / PEAK_FLOPS
+        return self.flops_per_chip / V5E_PEAK_FLOPS
 
     @property
     def t_memory(self) -> float:
-        return self.hbm_bytes_per_chip / HBM_BW
+        return self.hbm_bytes_per_chip / V5E_HBM_BW
 
     @property
     def t_collective(self) -> float:
-        return self.collective_bytes_per_chip / LINK_BW
+        return self.collective_bytes_per_chip / V5E_LINK_BW
 
     @property
     def bottleneck(self) -> str:

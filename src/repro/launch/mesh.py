@@ -43,3 +43,11 @@ def derive_serve_mesh(mesh: Mesh, mp: int) -> Mesh:
     total = devices.size
     assert total % mp == 0
     return Mesh(devices.reshape(total // mp, mp), ("dp", "mp"))
+
+
+def node_mesh(devices) -> Mesh:
+    """1-D ``node`` mesh, one gossip node (or an equal share of them) per
+    device.  Its axis is Auto-sharded: ``jax.make_mesh`` now defaults to
+    Explicit axes, under which the node-axis ``vmap`` of the train step
+    refuses a batch sharded differently from the params."""
+    return Mesh(np.asarray(devices), ("node",))

@@ -1,9 +1,11 @@
 """End-to-end decentralized training driver.
 
-On this CPU container it runs the *same* stacked program as the production mesh
-(1 device => all node slices colocated, math identical); on a real cluster the
-node axis shards over the (pod x data) axes per the TrainPlan.  Used by
-examples/train_lm.py for the ~100M-model few-hundred-step runs.
+On one device every gossip node is a slice of one stacked program.  With
+several devices and ``n_nodes`` a multiple of their count, the same program
+runs with the node axis sharded over a 1-D ``node`` mesh, one node (or an
+equal share of nodes) per chip, so each gossip shift is a real
+collective-permute.  Used by examples/train_lm.py and ``chip_smoke.py``.
+``--no-reduced`` trains the published widths.
 
 The gossip wire format and topology are specs, not flags-per-codec:
 ``--wire quant:8`` / ``--wire sparse:0.25:topk`` / ``--wire fp16`` /
@@ -25,10 +27,13 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
+import os
+import pathlib
 import time
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Sequence
 
 import jax
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from repro.checkpoint import latest_step, restore, save
 from repro.configs import get_config
@@ -44,6 +49,7 @@ from repro.analysis.jaxpr_checks import jit_compile_count
 from repro.distributed.failures import make_drop_spec
 from repro.distributed.gossip import make_gossip_plan
 from repro.distributed.wire import make_wire_format
+from repro.launch.mesh import node_mesh
 from repro.models.api import build_model
 from repro.optim import make_optimizer
 from repro.optim.schedules import linear_warmup_cosine
@@ -70,20 +76,64 @@ class TrainConfig:
     ckpt_dir: Optional[str] = None
     ckpt_every: int = 100
     log_every: int = 10
-    reduced: bool = True                # use the reduced config (CPU-scale)
+    reduced: bool = True                # width-cut config (CPU-scale); --no-reduced for published widths
+    remat: bool = False                 # recompute each layer in the backward pass (published widths)
 
 
 GOSSIP_ALGOS = ("naive", "dcd", "ecd", "choco", "deepsqueeze")
 
+# Persistent compilation cache when JAX_COMPILATION_CACHE_DIR is unset: a fixed
+# directory in the checkout (git-ignored), since the cache key includes it.
+_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
 
-def run_training(cfg: ArchConfig, tc: TrainConfig) -> Dict[str, Any]:
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and no
+    directory is set here; otherwise the cache is the checkout's
+    ``.jax_cache/``."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(_CACHE_DIR))
+    return str(_CACHE_DIR)
+
+
+def run_training(cfg: ArchConfig, tc: TrainConfig,
+                 devices: Optional[Sequence[Any]] = None) -> Dict[str, Any]:
+    """Train ``cfg`` under ``tc``; returns the history dict.
+
+    ``devices`` (default: every device of this process): with more than one,
+    and ``tc.n_nodes`` a multiple of their count, the node axis is sharded over
+    a 1-D ``node`` mesh of them; otherwise all nodes run stacked on the first.
+    Besides the logged metrics the history holds ``compile_s`` and
+    ``compiled_steps`` (one AOT-compiled step per phase segment: memory
+    analysis and HLO) and the final ``state``."""
     from repro.netsim.controller import Phase, PhasePlan
 
     model = build_model(cfg)
     opt = make_optimizer(tc.optimizer, **({"weight_decay": 0.01} if tc.optimizer == "adamw" else {}))
     sched = linear_warmup_cosine(tc.lr, tc.warmup, tc.steps)
     drop = make_drop_spec(tc.drop_rate, salt=tc.drop_salt)
-    loss_fn = lambda p, b: model.loss(p, b)
+    loss_fn = lambda p, b: model.loss(p, b, remat=tc.remat)
+
+    devices = list(jax.devices() if devices is None else devices)
+    mesh = node_mesh(devices) \
+        if len(devices) > 1 and tc.n_nodes % len(devices) == 0 else None
+    if mesh is None:
+        node_sh = rep_sh = SingleDeviceSharding(devices[0])
+    else:
+        node_sh, rep_sh = NamedSharding(mesh, P("node")), NamedSharding(mesh, P())
+
+    def shardings(tree):
+        # stacked leaves split over the node axis; scalars are replicated
+        return jax.tree.map(
+            lambda l: node_sh if l.ndim and l.shape[0] == tc.n_nodes else rep_sh,
+            tree)
+
+    def wire_of(phase: Phase):
+        return make_wire_format(phase.wire) if tc.algo in GOSSIP_ALGOS else None
 
     # one static {topology, wire} is just a one-phase plan — the phase loop
     # below IS the old single-segment loop in that case
@@ -91,51 +141,67 @@ def run_training(cfg: ArchConfig, tc: TrainConfig) -> Dict[str, Any]:
         else PhasePlan((Phase(0, tc.topology, tc.wire),))
     segments = pplan.segments(tc.steps)
 
-    def build_phase(phase: Phase):
-        plan = make_gossip_plan(phase.topology, tc.n_nodes)
-        wire = make_wire_format(phase.wire) if tc.algo in GOSSIP_ALGOS else None
-        step_fn = jax.jit(make_dist_train_step(loss_fn, tc.algo, opt, wire,
-                                               plan, sched, drop=drop,
-                                               gamma=tc.gamma))
-        return plan, step_fn
-
     params0 = model.init(jax.random.key(tc.seed))
     start = 0
     resume_step = latest_step(tc.ckpt_dir) if tc.ckpt_dir else None
     # a checkpoint at step s was written while executing under the phase that
     # governs step s-1 — the restore template must match THAT phase's aux keys
     init_phase = pplan.phase_at(max(0, (resume_step or 0) - 1))
-    init_wire = make_wire_format(init_phase.wire) \
-        if tc.algo in GOSSIP_ALGOS else None
-    state = init_dist_state(tc.algo, params0,
-                            make_gossip_plan(init_phase.topology, tc.n_nodes),
-                            opt, drop=drop, wire=init_wire)
+    init_plan = make_gossip_plan(init_phase.topology, tc.n_nodes)
+
+    def init_fn(p):
+        return init_dist_state(tc.algo, p, init_plan, opt, drop=drop,
+                               wire=wire_of(init_phase))
+
+    # built under jit so that no two state leaves share a buffer (the step
+    # donates the state, and the replica trees start equal to the params)
+    state = jax.jit(init_fn, out_shardings=shardings(
+        jax.eval_shape(init_fn, params0)))(params0)
+    del params0
     if resume_step is not None:
         state, manifest = restore(tc.ckpt_dir, state, resume_step)
+        state = jax.device_put(state, shardings(state))
         start = manifest["step"]
         print(f"resumed from step {start}")
 
     dc = DataConfig(vocab=cfg.vocab, seq_len=tc.seq_len, global_batch=tc.global_batch,
                     n_shards=tc.n_nodes, seed=tc.seed)
+
+    def batch_at(t):
+        batch = stacked_node_batches(dc, t, cfg)
+        return jax.device_put(batch, shardings(batch))
+
     hist = {"step": [], "loss": [], "consensus": [],
-            "phases": pplan.records(), "compiles_per_segment": []}
+            "phases": pplan.records(), "compiles_per_segment": [],
+            "compile_s": [], "compiled_steps": []}
     t0 = time.time()
     for seg_start, seg_stop, phase in segments:
         if seg_stop <= start:
             continue
-        plan, step_fn = build_phase(phase)
-        ran_steps = 0
+        plan = make_gossip_plan(phase.topology, tc.n_nodes)
         if seg_start > 0 and seg_start >= start:
             # phase boundary: resync aux to the new plan/wire (pure function
             # of params, so resume-at-boundary == run-through-boundary)
-            wire = make_wire_format(phase.wire) \
-                if tc.algo in GOSSIP_ALGOS else None
-            state = rekey_dist_state(state, tc.algo, plan, drop=drop,
-                                     wire=wire)
+            state = jax.jit(
+                lambda st: rekey_dist_state(st, tc.algo, plan, drop=drop,
+                                            wire=wire_of(phase)))(state)
             print(f"phase switch @ step {seg_start}: "
                   f"topology={phase.topology} wire={phase.wire}", flush=True)
-        for t in range(max(seg_start, start), seg_stop):
-            batch = stacked_node_batches(dc, t, cfg)
+        first = max(seg_start, start)
+        batch = batch_at(first)
+        state_sh = shardings(state)
+        step_fn = jax.jit(
+            make_dist_train_step(loss_fn, tc.algo, opt, wire_of(phase), plan,
+                                 sched, mesh=mesh, drop=drop, gamma=tc.gamma),
+            in_shardings=(state_sh, shardings(batch)),
+            out_shardings=(state_sh, rep_sh), donate_argnums=0)
+        tc0 = time.time()
+        hist["compiled_steps"].append(step_fn.lower(state, batch).compile())
+        hist["compile_s"].append(time.time() - tc0)
+        ran_steps = 0
+        for t in range(first, seg_stop):
+            if t > first:
+                batch = batch_at(t)
             state, metrics = step_fn(state, batch)
             ran_steps += 1
             if (t + 1) % tc.log_every == 0 or t == tc.steps - 1:
@@ -161,6 +227,7 @@ def run_training(cfg: ArchConfig, tc: TrainConfig) -> Dict[str, Any]:
             hist["compiles_per_segment"].append(n_compiles)
     hist["wall_s"] = time.time() - t0
     hist["final_loss"] = hist["loss"][-1]
+    hist["state"] = state
     return hist
 
 
@@ -172,16 +239,19 @@ def main():
         elif f.type in ("float", float):
             ap.add_argument(f"--{f.name.replace('_','-')}", type=float, default=f.default)
         elif f.type in ("bool", bool):
-            ap.add_argument(f"--{f.name.replace('_','-')}", action="store_true", default=f.default)
+            ap.add_argument(f"--{f.name.replace('_','-')}",
+                            action=argparse.BooleanOptionalAction, default=f.default)
         else:
             ap.add_argument(f"--{f.name.replace('_','-')}", default=f.default)
     args = ap.parse_args()
     tc = TrainConfig(**{f.name: getattr(args, f.name) for f in dataclasses.fields(TrainConfig)})
+    use_compile_cache()
     cfg = get_config(tc.arch) if tc.arch else get_config("granite-3-2b")
     if tc.reduced:
         cfg = cfg.reduced()
     hist = run_training(cfg, tc)
-    print(json.dumps({k: v for k, v in hist.items() if not isinstance(v, list)}, indent=2))
+    print(json.dumps({k: v for k, v in hist.items() if isinstance(v, (int, float))},
+                     indent=2))
 
 
 if __name__ == "__main__":

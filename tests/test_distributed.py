@@ -21,6 +21,7 @@ from repro.core.testbed import make_problem
 from repro.distributed.decentralized import init_dist_state, make_dist_train_step
 from repro.distributed.gossip import make_gossip_plan
 from repro.distributed.wire import QuantWire, SparseWire
+from repro.launch.mesh import node_mesh
 from repro.optim import sgd
 from repro.optim.schedules import constant
 
@@ -160,7 +161,7 @@ def test_sharded_gossip_decode_matches_inline(algo, codec, topo_spec):
 
     n, d = 8, 256
     plan = make_gossip_plan(topo_spec, n)
-    mesh = jax.make_mesh((8,), ("node",))
+    mesh = node_mesh(jax.devices()[:8])
     step_mesh = make_dist_train_step(_toy_loss, algo, sgd(), codec, plan,
                                      constant(0.05), mesh=mesh)
     step_inline = jax.jit(make_dist_train_step(_toy_loss, algo, sgd(), codec,
@@ -485,13 +486,13 @@ def test_dist_step_uses_fused_sparse_kernel(mode):
     batch = _toy_batch(jax.random.key(0), n, d=d)
     counts = kernel_call_counts(str(jax.make_jaxpr(step)(state, batch)))
     # one fused call per decode site: self + 2 neighbors on the ring
-    assert counts["_sparse_scatter_axpy_kernel"] == \
+    assert counts["sparse_scatter_axpy"] == \
         expected_kernel_calls("dcd", plan, wire, state.params) == 3
 
     small = init_dist_state("dcd", jnp.zeros((8,)), n, sgd())
     counts_s = kernel_call_counts(str(jax.make_jaxpr(step)(
         small, _toy_batch(jax.random.key(0), n, d=8))))
-    assert counts_s["_sparse_scatter_axpy_kernel"] == \
+    assert counts_s["sparse_scatter_axpy"] == \
         expected_kernel_calls("dcd", plan, wire, small.params) == 0
 
 
@@ -535,20 +536,20 @@ def test_dist_step_uses_fused_axpy_kernel(algo):
     batch = _toy_batch(jax.random.key(0), n, d=d)
     counts = kernel_call_counts(str(jax.make_jaxpr(step)(state, batch)))
     # one fused call per decode site: self + one per neighbor shift
-    assert counts["_unpack_dequant_axpy_kernel"] == \
+    assert counts["unpack_dequant_axpy"] == \
         expected_kernel_calls(algo, plan, wire, state.params) == 3
 
     wire8 = QuantWire(bits=8, block=128)
     step8 = make_dist_train_step(_toy_loss, algo, sgd(), wire8, plan, constant(0.05))
     counts8 = kernel_call_counts(str(jax.make_jaxpr(step8)(state, batch)))
-    assert counts8["_unpack_dequant_axpy_kernel"] == \
+    assert counts8["unpack_dequant_axpy"] == \
         expected_kernel_calls(algo, plan, wire8, state.params) == 0
 
     # a tiny leaf (block 32 < 128 lanes) must NOT reach the kernel
     small = init_dist_state(algo, jnp.zeros((8,)), n, sgd())
     counts_s = kernel_call_counts(str(jax.make_jaxpr(step)(
         small, _toy_batch(jax.random.key(0), n, d=8))))
-    assert counts_s["_unpack_dequant_axpy_kernel"] == \
+    assert counts_s["unpack_dequant_axpy"] == \
         expected_kernel_calls(algo, plan, wire, small.params) == 0
 
 
@@ -887,7 +888,7 @@ def test_schedule_degree_vs_dense_plan_permute_count():
     # the |union| rolled-payload decodes per round are exactly what
     # GossipPlan/GossipSchedule.replica_payloads (and netsim's
     # decentralized_lp charge) count — decode_sites() is that same formula
-    assert counts["_unpack_dequant_axpy_kernel"] == \
+    assert counts["unpack_dequant_axpy"] == \
         expected_kernel_calls("dcd", sched, wire, state.params) == \
         sched.period * (1 + len(sched.shift_union))
     assert sched.replica_payloads == sched.period * len(sched.shift_union) == 9
@@ -898,7 +899,7 @@ def test_schedule_degree_vs_dense_plan_permute_count():
     state_d = init_dist_state("dcd", jnp.zeros((d,)), dense, sgd())
     counts_d = kernel_call_counts(str(jax.make_jaxpr(step_d)(state_d, batch)))
     # dense: 1 round, 1 self + 7 replica decodes — more aux, more permutes
-    assert counts_d["_unpack_dequant_axpy_kernel"] == \
+    assert counts_d["unpack_dequant_axpy"] == \
         expected_kernel_calls("dcd", dense, wire, state_d.params) == \
         1 + dense.degree
     assert dense.degree == n - 1 > sched.degree

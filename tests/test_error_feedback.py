@@ -4,7 +4,7 @@ runtime, plus the 1-bit sign codec they headline with.
 The contract under test, layer by layer:
 
 - The sign codec holds the three-implementation invariant: the Pallas kernel
-  (interpret mode off-TPU), the jnp oracle, and the sharding-preserving
+  (interpret mode on the CPU), the jnp oracle, and the sharding-preserving
   ``SignWire`` codec produce bit-identical packed words and scales on the
   width-1 ``pack_uint`` stream layout, and the fused axpy agrees with the
   oracle to the established kernel tolerance (rtol 1e-5 / atol 1e-6 — FMA
@@ -38,6 +38,7 @@ from repro.distributed.failures import make_drop_spec
 from repro.distributed.gossip import make_gossip_plan
 from repro.distributed.wire import SignWire, make_wire_format
 from repro.kernels.quant import sign_pack_2d, unpack_sign_axpy_2d
+from repro.launch.mesh import node_mesh
 from repro.kernels.ref import (
     pack_uint,
     sign_pack_2d_ref,
@@ -339,7 +340,7 @@ def test_sharded_mesh_sign_drop_matches_stacked_reference(algo):
     plan = make_gossip_plan("ring", n)
     wire = SignWire(block=128)
     drop = make_drop_spec(0.2, salt=4)
-    mesh = jax.make_mesh((8,), ("node",))
+    mesh = node_mesh(jax.devices()[:8])
     step_mesh = make_dist_train_step(_toy_loss, algo, sgd(), wire, plan,
                                      constant(0.05), mesh=mesh, drop=drop,
                                      gamma=0.7)

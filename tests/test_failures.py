@@ -38,6 +38,7 @@ from repro.distributed.gossip import (
     realized_mixing_matrix,
 )
 from repro.distributed.wire import QuantWire, SparseWire
+from repro.launch.mesh import node_mesh
 from repro.optim import sgd
 from repro.optim.schedules import constant
 
@@ -347,7 +348,7 @@ def test_sharded_mesh_drop_matches_stacked_reference(algo):
     plan = make_gossip_plan("ring", n)
     wire = QuantWire(bits=3, block=128)
     drop = make_drop_spec(0.2, salt=4)
-    mesh = jax.make_mesh((8,), ("node",))
+    mesh = node_mesh(jax.devices()[:8])
     step_mesh = make_dist_train_step(_toy_loss, algo, sgd(), wire, plan,
                                      constant(0.05), mesh=mesh, drop=drop)
     state_m = init_dist_state(algo, jnp.zeros((d,)), plan, sgd(), drop=drop)

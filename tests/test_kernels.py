@@ -191,3 +191,16 @@ def test_packed_kernel_property_sweep(rows, cols, bits, seed):
     np.testing.assert_array_equal(np.asarray(pk), np.asarray(pr))
     np.testing.assert_allclose(np.asarray(sk), np.asarray(sr), rtol=1e-7)
     assert pk.shape == (rows, cols * bits // 32) and pk.dtype == jnp.uint32
+
+
+@pytest.mark.parametrize("backend,want", [("tpu", False), ("cpu", True),
+                                          ("gpu", None)])
+def test_interpret_kernels_only_on_cpu(monkeypatch, backend, want):
+    """Mosaic on a TPU, interpret mode on the CPU, and no silent fallback on
+    any other backend."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if want is None:
+        with pytest.raises(RuntimeError, match="gpu"):
+            kops.interpret_kernels()
+    else:
+        assert kops.interpret_kernels() is want

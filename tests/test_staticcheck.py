@@ -77,7 +77,7 @@ FILE_RULE_FIXTURES = {
     "RL011": ("src/repro/x.py",
               "import time, jax\nk = jax.random.key(int(time.time()))\n"),
     "RL021": ("src/repro/core/x.py",
-              "from jax.experimental.shard_map import shard_map\n"),
+              "from jax import shard_map\n"),
 }
 
 

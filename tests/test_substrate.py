@@ -63,6 +63,44 @@ def test_stacked_node_batches():
     assert sb["tokens"].shape == (4, 2, 8)
 
 
+def test_stacked_node_batches_at_a_published_vocab():
+    """mamba2-370m's 50,280 tokens: one call yields every node's batch, equal
+    to that node's own ``sample_batch`` (no vocab x vocab table exists)."""
+    cfg = DataConfig(vocab=get_config("mamba2-370m").vocab, seq_len=16,
+                     global_batch=6, n_shards=3, seed=1)
+    sb = stacked_node_batches(cfg, 4)
+    assert sb["tokens"].shape == (3, 2, 16)
+    assert int(sb["tokens"].max()) < cfg.vocab
+    for s in range(3):
+        b = sample_batch(cfg, 4, s)
+        for k in ("tokens", "labels"):
+            np.testing.assert_array_equal(np.asarray(sb[k][s]), np.asarray(b[k]))
+
+
+@pytest.mark.parametrize("env", [None, "elsewhere"])
+def test_compile_cache_placed_from_outside(monkeypatch, tmp_path, env):
+    """JAX_COMPILATION_CACHE_DIR wins and no directory is set in code;
+    otherwise the cache is the checkout's fixed .jax_cache/."""
+    from repro.launch import train
+
+    before = jax.config.jax_compilation_cache_dir
+    if env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / env))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        got = train.use_compile_cache()
+        if env:
+            assert got == str(tmp_path / env)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+            assert got == os.path.join(root, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
 # ------------------------------------------------------------------ optim
 
 def _quad_problem():
