@@ -45,6 +45,13 @@ and resynced at phase boundaries by ``rekey_dist_state(..., wire=...)``.
 Stochastic rounding uses the same counter-based PCG hash as the Pallas kernel
 (kernels/ref.py), seeded by (step, salt, leaf) — deterministic, key-free inside
 the compiled step, and identical to the stacked reference's seeding.
+
+Every phase of the step is traced under a ``jax.named_scope`` (:data:`PHASES`),
+so each compiled instruction's ``op_name`` metadata names the phase it
+belongs to and a profile of the step can be split by phase.  Scopes do not
+nest across phases: an argument evaluated at a call site (``_roll(payload,
+s)`` handed to the decode) carries its own phase.  The scopes are metadata
+only: the compiled program is otherwise the same instruction for instruction.
 """
 from __future__ import annotations
 
@@ -74,7 +81,25 @@ from repro.distributed.gossip import (
 from repro.distributed.wire import WireFormat, make_wire_format
 from repro.optim.optimizers import Optimizer, apply_updates
 
-_roll = roll_tree
+# the named scopes of the train step, one per phase; the forward, backward and
+# recomputed parts of ``fwd_bwd`` carry JAX's own jvp / transpose /
+# rematted_computation markers (``optimizer`` is set in optim/optimizers.py)
+PHASES = ("fwd_bwd", "optimizer", "encode", "permute", "decode", "mix",
+          "step_metrics")
+
+
+def _scoped(name: str, fn: Callable) -> Callable:
+    """``fn`` traced under ``jax.named_scope(name)``."""
+    def scoped(*args, **kwargs):
+        with jax.named_scope(name):
+            return fn(*args, **kwargs)
+    return scoped
+
+
+_roll = _scoped("permute", roll_tree)
+_mix = _scoped("mix", plan_mix)
+_mix_gated = _scoped("mix", plan_mix_gated)
+_select_delivered = _scoped("decode", select_delivered)
 
 
 # --------------------------------------------------------------- state
@@ -348,6 +373,9 @@ def make_dist_train_step(
             return jax.tree.map(
                 lambda a, d: (acc_weight * a + weight * d).astype(a.dtype),
                 acc_tree, dec)
+    if dec_axpy is not None:
+        dec_axpy = _scoped("decode", dec_axpy)
+    decode_tree = _scoped("decode", wire.decode_tree) if wire is not None else None
 
     wire_aux_key = wire.aux_name if (wire is not None and wire.stateful) \
         else None
@@ -357,14 +385,15 @@ def make_dist_train_step(
         # factors, keyed ``wire.aux_name`` in the DistState aux — present iff
         # init_dist_state was given the wire).  Stateless formats pass the
         # aux dict through untouched, so the compiled program is unchanged.
-        if wire_aux_key is None:
-            tdef, payloads = wire.encode_tree(tree, enc_step, salt)
+        with jax.named_scope("encode"):
+            if wire_aux_key is None:
+                tdef, payloads = wire.encode_tree(tree, enc_step, salt)
+                return tdef, payloads, aux
+            aux = dict(aux)
+            tdef, payloads, waux = wire.encode_tree_stateful(
+                tree, enc_step, salt, aux[wire_aux_key])
+            aux[wire_aux_key] = waux
             return tdef, payloads, aux
-        aux = dict(aux)
-        tdef, payloads, waux = wire.encode_tree_stateful(
-            tree, enc_step, salt, aux[wire_aux_key])
-        aux[wire_aux_key] = waux
-        return tdef, payloads, aux
 
     grad_fn = jax.vmap(jax.value_and_grad(loss_fn, has_aux=True), spmd_axis_name="node")
 
@@ -384,23 +413,32 @@ def make_dist_train_step(
     # its dropped edges via a post-decode select (the fused axpy kernel keeps
     # its scalar-weight contract; the select fuses into the same pass).
 
+    # the delivery masks, freshness and gates set the mixing weights, so they
+    # are traced as part of ``mix``
+
     def _round_masks(enc_step, shifts):
-        return {s: edge_drop_mask(n_nodes, s, enc_step, drop) for s in shifts}
+        with jax.named_scope("mix"):
+            return {s: edge_drop_mask(n_nodes, s, enc_step, drop) for s in shifts}
 
     def _advance_freshness(aux_d, masks):
-        for s in union:
-            fk = fresh_key(s, drop.salt)
-            aux_d[fk] = update_freshness(aux_d[fk], masks[s], drop.decay)
-        return aux_d
+        with jax.named_scope("mix"):
+            for s in union:
+                fk = fresh_key(s, drop.salt)
+                aux_d[fk] = update_freshness(aux_d[fk], masks[s], drop.decay)
+            return aux_d
+
+    def _gates(aux_d, masks, shifts):
+        with jax.named_scope("mix"):
+            return {s: masks[s] * aux_d[fresh_key(s, drop.salt)] for s in shifts}
 
     def _dpsgd_round(rnd, enc_step, carry, upd):
         X_cur, aux_d = carry
         nbrs = {s: _roll(X_cur, s) for s in rnd.shift_list}
         if drop is None:
-            X_mix = plan_mix(rnd, X_cur, nbrs)
+            X_mix = _mix(rnd, X_cur, nbrs)
         else:
-            X_mix = plan_mix_gated(rnd, X_cur, nbrs,
-                                   _round_masks(enc_step, rnd.shift_list))
+            X_mix = _mix_gated(rnd, X_cur, nbrs,
+                               _round_masks(enc_step, rnd.shift_list))
         if upd is not None:
             X_mix = apply_updates(X_mix, upd)
         return X_mix, aux_d
@@ -409,14 +447,14 @@ def make_dist_train_step(
         # compress the exchanged models directly — provably non-convergent
         X_cur, aux_d = carry
         tdef, payload, aux_d = encode_tree(X_cur, enc_step, salt=1, aux=aux_d)
-        dec_self = wire.decode_tree(tdef, payload, X_cur)
-        nbrs = {s: wire.decode_tree(tdef, _roll(payload, s), X_cur)
+        dec_self = decode_tree(tdef, payload, X_cur)
+        nbrs = {s: decode_tree(tdef, _roll(payload, s), X_cur)
                 for s in rnd.shift_list}
         if drop is None:
-            X_mix = plan_mix(rnd, dec_self, nbrs)
+            X_mix = _mix(rnd, dec_self, nbrs)
         else:
-            X_mix = plan_mix_gated(rnd, dec_self, nbrs,
-                                   _round_masks(enc_step, rnd.shift_list))
+            X_mix = _mix_gated(rnd, dec_self, nbrs,
+                               _round_masks(enc_step, rnd.shift_list))
         if upd is not None:
             X_mix = apply_updates(X_mix, upd)
         return X_mix, aux_d
@@ -427,16 +465,16 @@ def make_dist_train_step(
         reps = {s: aux_d[f"rep{s:+d}"] for s in rnd.shift_list}
         if drop is None:
             masks = None
-            X_half = plan_mix(rnd, X_cur, reps)
+            X_half = _mix(rnd, X_cur, reps)
         else:
             masks = _round_masks(enc_step, union)
             aux_d = _advance_freshness(aux_d, masks)
-            gates = {s: masks[s] * aux_d[fresh_key(s, drop.salt)]
-                     for s in rnd.shift_list}
-            X_half = plan_mix_gated(rnd, X_cur, reps, gates)
+            X_half = _mix_gated(rnd, X_cur, reps,
+                                _gates(aux_d, masks, rnd.shift_list))
         if upd is not None:
             X_half = apply_updates(X_half, upd)
-        Z = jax.tree.map(lambda a, b: a - b, X_half, X_cur)
+        with jax.named_scope("encode"):
+            Z = jax.tree.map(lambda a, b: a - b, X_half, X_cur)
         tdef, payload, aux_d = encode_tree(Z, enc_step, salt=2, aux=aux_d)
         # receive side: one fused unpack+dequant+axpy kernel per leaf; every
         # union replica advances with the rolled payload so rep{s} keeps
@@ -446,30 +484,32 @@ def make_dist_train_step(
         for s in union:
             rep = dec_axpy(tdef, _roll(payload, s), aux_d[f"rep{s:+d}"], 1.0)
             if masks is not None:
-                rep = select_delivered(masks[s], rep, aux_d[f"rep{s:+d}"])
+                rep = _select_delivered(masks[s], rep, aux_d[f"rep{s:+d}"])
             aux_d[f"rep{s:+d}"] = rep
         return X_cur, aux_d
 
     def _ecd_round(rnd, enc_step, carry, upd):
         X_cur, aux_d = carry
         aux_d = dict(aux_d)
-        s_t = (enc_step + 1).astype(jnp.float32)
+        with jax.named_scope("encode"):
+            s_t = (enc_step + 1).astype(jnp.float32)
         tildes = {s: aux_d[f"tilde{s:+d}"] for s in rnd.shift_list}
         if drop is None:
             masks = None
-            X_mix = plan_mix(rnd, aux_d["tilde_self"], tildes)
+            X_mix = _mix(rnd, aux_d["tilde_self"], tildes)
         else:
             masks = _round_masks(enc_step, union)
             aux_d = _advance_freshness(aux_d, masks)
-            gates = {s: masks[s] * aux_d[fresh_key(s, drop.salt)]
-                     for s in rnd.shift_list}
-            X_mix = plan_mix_gated(rnd, aux_d["tilde_self"], tildes, gates)
+            X_mix = _mix_gated(rnd, aux_d["tilde_self"], tildes,
+                               _gates(aux_d, masks, rnd.shift_list))
         X_next = apply_updates(X_mix, upd) if upd is not None else X_mix
-        Z = jax.tree.map(lambda a, b: (1.0 - 0.5 * s_t) * a + 0.5 * s_t * b,
-                         X_cur, X_next)
+        with jax.named_scope("encode"):
+            Z = jax.tree.map(lambda a, b: (1.0 - 0.5 * s_t) * a + 0.5 * s_t * b,
+                             X_cur, X_next)
         tdef, payload, aux_d = encode_tree(Z, enc_step, salt=3, aux=aux_d)
-        est_decay = 1.0 - 2.0 / s_t
-        blend = 2.0 / s_t
+        with jax.named_scope("decode"):
+            est_decay = 1.0 - 2.0 / s_t
+            blend = 2.0 / s_t
         # est_decay*tilde + blend*decode in ONE fused pass per leaf: the decay
         # scale rides the kernel's acc_weight operand, so no pre-scaled
         # f32 accumulator is ever written to HBM
@@ -479,7 +519,7 @@ def make_dist_train_step(
             est = dec_axpy(tdef, _roll(payload, s), aux_d[f"tilde{s:+d}"],
                            blend, est_decay)
             if masks is not None:
-                est = select_delivered(masks[s], est, aux_d[f"tilde{s:+d}"])
+                est = _select_delivered(masks[s], est, aux_d[f"tilde{s:+d}"])
             aux_d[f"tilde{s:+d}"] = est
         return X_next, aux_d
 
@@ -495,7 +535,8 @@ def make_dist_train_step(
             masks = _round_masks(enc_step, union)
             aux_d = _advance_freshness(aux_d, masks)
         X_half = apply_updates(X_cur, upd) if upd is not None else X_cur
-        Z = jax.tree.map(lambda a, b: a - b, X_half, aux_d["hat_self"])
+        with jax.named_scope("encode"):
+            Z = jax.tree.map(lambda a, b: a - b, X_half, aux_d["hat_self"])
         tdef, payload, aux_d = encode_tree(Z, enc_step, salt=4, aux=aux_d)
         # every node decodes the SAME words it sent, so hat_self stays equal
         # to every neighbor's hat{s} of this node — the shared-estimate
@@ -505,18 +546,18 @@ def make_dist_train_step(
         for s in union:
             hat = dec_axpy(tdef, _roll(payload, s), aux_d[f"hat{s:+d}"], 1.0)
             if masks is not None:
-                hat = select_delivered(masks[s], hat, aux_d[f"hat{s:+d}"])
+                hat = _select_delivered(masks[s], hat, aux_d[f"hat{s:+d}"])
             aux_d[f"hat{s:+d}"] = hat
         hats = {s: aux_d[f"hat{s:+d}"] for s in rnd.shift_list}
         if masks is None:
-            mixed = plan_mix(rnd, hat_self, hats)
+            mixed = _mix(rnd, hat_self, hats)
         else:
-            gates = {s: masks[s] * aux_d[fresh_key(s, drop.salt)]
-                     for s in rnd.shift_list}
-            mixed = plan_mix_gated(rnd, hat_self, hats, gates)
-        X_new = jax.tree.map(
-            lambda x, m, h: (x + gamma * (m - h)).astype(x.dtype),
-            X_half, mixed, hat_self)
+            mixed = _mix_gated(rnd, hat_self, hats,
+                               _gates(aux_d, masks, rnd.shift_list))
+        with jax.named_scope("mix"):
+            X_new = jax.tree.map(
+                lambda x, m, h: (x + gamma * (m - h)).astype(x.dtype),
+                X_half, mixed, hat_self)
         return X_new, aux_d
 
     def _deepsqueeze_round(rnd, enc_step, carry, upd):
@@ -533,20 +574,23 @@ def make_dist_train_step(
         X_cur, aux_d = carry
         aux_d = dict(aux_d)
         X_half = apply_updates(X_cur, upd) if upd is not None else X_cur
-        V = jax.tree.map(lambda x, e: x + e, X_half, aux_d["err_self"])
+        with jax.named_scope("encode"):
+            V = jax.tree.map(lambda x, e: x + e, X_half, aux_d["err_self"])
         tdef, payload, aux_d = encode_tree(V, enc_step, salt=5, aux=aux_d)
         aux_d["err_self"] = dec_axpy(tdef, payload, V, -1.0)
-        zero = jax.tree.map(jnp.zeros_like, X_half)
+        with jax.named_scope("decode"):
+            zero = jax.tree.map(jnp.zeros_like, X_half)
         d_self = dec_axpy(tdef, payload, zero, 1.0)
         nbrs = {s: dec_axpy(tdef, _roll(payload, s), zero, 1.0)
                 for s in rnd.shift_list}
         if drop is None:
-            mixed = plan_mix(rnd, d_self, nbrs)
+            mixed = _mix(rnd, d_self, nbrs)
         else:
-            mixed = plan_mix_gated(rnd, d_self, nbrs,
-                                   _round_masks(enc_step, rnd.shift_list))
-        X_new = jax.tree.map(lambda x, m, d: (x + (m - d)).astype(x.dtype),
-                             X_half, mixed, d_self)
+            mixed = _mix_gated(rnd, d_self, nbrs,
+                               _round_masks(enc_step, rnd.shift_list))
+        with jax.named_scope("mix"):
+            X_new = jax.tree.map(lambda x, m, d: (x + (m - d)).astype(x.dtype),
+                                 X_half, mixed, d_self)
         return X_new, aux_d
 
     round_fn = {"dpsgd": _dpsgd_round, "naive": _naive_round,
@@ -555,16 +599,20 @@ def make_dist_train_step(
                 "deepsqueeze": _deepsqueeze_round}.get(algo)
 
     def step(state: DistState, batch: Any) -> Tuple[DistState, Dict[str, jax.Array]]:
-        (losses, metrics), grads = grad_fn(state.params, batch)
-        lr = lr_schedule(state.step)
+        with jax.named_scope("fwd_bwd"):
+            (losses, metrics), grads = grad_fn(state.params, batch)
+        with jax.named_scope("optimizer"):
+            lr = lr_schedule(state.step)
         updates, opt_state = opt.update(grads, state.opt, state.params, lr)
         X, aux = state.params, dict(state.aux)
 
         if algo == "cpsgd":
             # AllReduce baseline: identical replicas apply the node-mean update.
-            mean_upd = jax.tree.map(
-                lambda u: jnp.broadcast_to(jnp.mean(u, axis=0, keepdims=True), u.shape),
-                updates)
+            with jax.named_scope("mix"):
+                mean_upd = jax.tree.map(
+                    lambda u: jnp.broadcast_to(jnp.mean(u, axis=0, keepdims=True),
+                                               u.shape),
+                    updates)
             X_new = apply_updates(X, mean_upd)
 
         elif time_varying:
@@ -594,15 +642,16 @@ def make_dist_train_step(
             if grad_round is None:
                 X_new = apply_updates(X_new, updates)
 
-        consensus = sum(
-            jnp.sum((l - jnp.mean(l, axis=0, keepdims=True)) ** 2)
-            for l in jax.tree.leaves(X_new))
-        out_metrics = {
-            "loss": jnp.mean(losses),
-            "lr": lr,
-            "consensus": consensus,
-            **{k: jnp.mean(v) for k, v in metrics.items()},
-        }
+        with jax.named_scope("step_metrics"):
+            consensus = sum(
+                jnp.sum((l - jnp.mean(l, axis=0, keepdims=True)) ** 2)
+                for l in jax.tree.leaves(X_new))
+            out_metrics = {
+                "loss": jnp.mean(losses),
+                "lr": lr,
+                "consensus": consensus,
+                **{k: jnp.mean(v) for k, v in metrics.items()},
+            }
         return DistState(params=X_new, opt=opt_state, aux=aux,
                          step=state.step + 1), out_metrics
 

@@ -34,15 +34,16 @@ def sgd(momentum: float = 0.0, weight_decay: float = 0.0, nesterov: bool = False
         return OptState(step=jnp.zeros((), jnp.int32), m=m)
 
     def update(grads, state, params, lr):
-        if weight_decay:
-            grads = jax.tree.map(lambda g, p: g + weight_decay * p, grads, params)
-        if momentum:
-            m = jax.tree.map(lambda mm, g: momentum * mm + g, state.m, grads)
-            eff = jax.tree.map(lambda mm, g: g + momentum * mm, m, grads) if nesterov else m
-            upd = jax.tree.map(lambda u: -lr * u, eff)
-            return upd, OptState(step=state.step + 1, m=m)
-        upd = jax.tree.map(lambda g: -lr * g, grads)
-        return upd, OptState(step=state.step + 1)
+        with jax.named_scope("optimizer"):
+            if weight_decay:
+                grads = jax.tree.map(lambda g, p: g + weight_decay * p, grads, params)
+            if momentum:
+                m = jax.tree.map(lambda mm, g: momentum * mm + g, state.m, grads)
+                eff = jax.tree.map(lambda mm, g: g + momentum * mm, m, grads) if nesterov else m
+                upd = jax.tree.map(lambda u: -lr * u, eff)
+                return upd, OptState(step=state.step + 1, m=m)
+            upd = jax.tree.map(lambda g: -lr * g, grads)
+            return upd, OptState(step=state.step + 1)
 
     return Optimizer("sgd", init, update)
 
@@ -54,21 +55,25 @@ def adamw(b1: float = 0.9, b2: float = 0.95, eps: float = 1e-8,
         return OptState(step=jnp.zeros((), jnp.int32), m=z, v=jax.tree.map(jnp.zeros_like, params))
 
     def update(grads, state, params, lr):
-        t = state.step + 1
-        m = jax.tree.map(lambda mm, g: b1 * mm + (1 - b1) * g, state.m, grads)
-        v = jax.tree.map(lambda vv, g: b2 * vv + (1 - b2) * g * g, state.v, grads)
-        bc1 = 1 - b1 ** t.astype(jnp.float32)
-        bc2 = 1 - b2 ** t.astype(jnp.float32)
-        upd = jax.tree.map(
-            lambda mm, vv, p: -lr * ((mm / bc1) / (jnp.sqrt(vv / bc2) + eps) + weight_decay * p),
-            m, v, params)
-        return upd, OptState(step=t, m=m, v=v)
+        with jax.named_scope("optimizer"):
+            t = state.step + 1
+            m = jax.tree.map(lambda mm, g: b1 * mm + (1 - b1) * g, state.m, grads)
+            v = jax.tree.map(lambda vv, g: b2 * vv + (1 - b2) * g * g, state.v, grads)
+            bc1 = 1 - b1 ** t.astype(jnp.float32)
+            bc2 = 1 - b2 ** t.astype(jnp.float32)
+            upd = jax.tree.map(
+                lambda mm, vv, p: -lr * ((mm / bc1) / (jnp.sqrt(vv / bc2) + eps)
+                                         + weight_decay * p),
+                m, v, params)
+            return upd, OptState(step=t, m=m, v=v)
 
     return Optimizer("adamw", init, update)
 
 
 def apply_updates(params, updates):
-    return jax.tree.map(lambda p, u: (p + u).astype(p.dtype), params, updates)
+    # the train step's optimizer phase (distributed/decentralized.py PHASES)
+    with jax.named_scope("optimizer"):
+        return jax.tree.map(lambda p, u: (p + u).astype(p.dtype), params, updates)
 
 
 def global_norm(tree) -> jax.Array:
