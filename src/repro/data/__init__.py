@@ -1,1 +1,2 @@
-from repro.data.pipeline import DataConfig, sample_batch, stacked_node_batches
+from repro.data.pipeline import (DataConfig, pipeline_stats, sample_batch,
+                                 stacked_node_batches)

@@ -38,7 +38,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 from repro.checkpoint import latest_step, restore, save
 from repro.configs import get_config
 from repro.configs.base import ArchConfig
-from repro.data import DataConfig, stacked_node_batches
+from repro.data import DataConfig, pipeline_stats, stacked_node_batches
 from repro.distributed.decentralized import (
     DistState,
     init_dist_state,
@@ -234,6 +234,7 @@ def run_training(cfg: ArchConfig, tc: TrainConfig,
                     "exactly 1) — step inputs must be shape/dtype-stable "
                     "within a segment")
     hist["wall_s"] = time.time() - t0
+    print(f"input pipeline: {pipeline_stats(dc, cfg)}", flush=True)
     hist["final_loss"] = hist["loss"][-1]
     hist["state"] = state
     return hist

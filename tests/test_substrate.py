@@ -1,5 +1,6 @@
 """Data pipeline, optimizer, schedule, checkpoint tests."""
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -9,7 +10,8 @@ from hypothesis import given, settings, strategies as st
 
 from repro.checkpoint import latest_step, restore, save
 from repro.configs import get_config
-from repro.data import DataConfig, sample_batch, stacked_node_batches
+from repro.data import DataConfig, pipeline_stats, sample_batch, stacked_node_batches
+from repro.data import pipeline
 from repro.optim import adamw, make_optimizer, sgd
 from repro.optim.optimizers import apply_updates, clip_by_global_norm, global_norm
 from repro.optim.schedules import cosine_decay, inv_sqrt_decay, linear_warmup_cosine
@@ -75,6 +77,106 @@ def test_stacked_node_batches_at_a_published_vocab():
         b = sample_batch(cfg, 4, s)
         for k in ("tokens", "labels"):
             np.testing.assert_array_equal(np.asarray(sb[k][s]), np.asarray(b[k]))
+
+
+def _direct(cfg, step, arch=None):
+    """Every shard's batch of ``step`` from the walk of that step alone."""
+    return pipeline._node_batches(cfg, arch, jnp.asarray(step, jnp.int32),
+                                  jnp.arange(cfg.n_shards, dtype=jnp.int32))
+
+
+def _assert_batches_equal(got, want):
+    assert got.keys() == want.keys()
+    for k in want:
+        np.testing.assert_array_equal(np.asarray(got[k]), np.asarray(want[k]))
+
+
+@pytest.mark.parametrize("vocab,seq_len,global_batch,n_shards,arch_name,k", [
+    (64, 16, 4, 2, None, 16),                         # a small vocab
+    ("mamba2-370m", 64, 16, 2, None, 4),              # a published vocab
+    ("arch", 64, 2, 1, "internvl2-76b", 8),           # s_text 56: K divides it
+    ("arch", 63, 2, 1, "internvl2-76b", 1),           # s_text 55 forces K = 1
+])
+def test_pipelined_walk_equals_the_direct_one(vocab, seq_len, global_batch, n_shards,
+                                              arch_name, k):
+    """3K+1 consecutive steps of the pipelined walk give every id (and
+    frontend embedding) the step's own direct walk gives, after one cold
+    start; at K = 1 no pipeline is kept."""
+    arch = get_config(arch_name).reduced() if arch_name else None
+    if vocab == "arch":
+        vocab = arch.vocab
+    elif isinstance(vocab, str):
+        vocab = get_config(vocab).vocab
+    cfg = DataConfig(vocab=vocab, seq_len=seq_len, global_batch=global_batch,
+                     n_shards=n_shards, seed=11)
+    assert pipeline_stats(cfg, arch)["k"] == k
+    first = 1000
+    for t in range(first, first + 3 * k + 1):
+        _assert_batches_equal(stacked_node_batches(cfg, t, arch), _direct(cfg, t, arch))
+    calls = 0 if k == 1 else 3 * k + 1 + k - 1
+    assert pipeline_stats(cfg, arch) == {"segment_calls": calls,
+                                         "cold_starts": int(k > 1), "k": k}
+
+
+def test_pipelined_walk_cold_starts_on_a_jump():
+    """A step that does not follow the last one refills the pipeline: a jump
+    backwards (a resume) and one forwards each count one cold start, and the
+    ids stay the direct walk's."""
+    cfg = DataConfig(vocab=96, seq_len=32, global_batch=8, n_shards=4, seed=5)
+    k = pipeline_stats(cfg)["k"]
+    assert k == 8
+    steps = [0, 1, 2, 3, 1, 2, 3, 40, 41]
+    for t in steps:
+        _assert_batches_equal(stacked_node_batches(cfg, t), _direct(cfg, t))
+    assert pipeline_stats(cfg) == {"segment_calls": len(steps) + 3 * (k - 1),
+                                   "cold_starts": 3, "k": k}
+
+
+def test_eval_shape_leaves_the_pipeline_alone():
+    """Under ``jax.eval_shape`` the call gives the batch's shapes and dtypes
+    and neither counts nor stores anything (no tracer is kept)."""
+    cfg = DataConfig(vocab=64, seq_len=16, global_batch=4, n_shards=2, seed=7)
+    stacked_node_batches(cfg, 3)
+    p = pipeline._PIPELINES[(cfg, None)]
+    stats, carry, factors = pipeline_stats(cfg), p.carry, p.factors
+    shapes = jax.eval_shape(lambda: stacked_node_batches(cfg, 9))
+    assert pipeline_stats(cfg) == stats and p.next_step == 4
+    assert p.carry is carry and p.factors is factors
+    want = _direct(cfg, 9)
+    assert {k: (v.shape, v.dtype) for k, v in shapes.items()} == \
+        {k: (v.shape, v.dtype) for k, v in want.items()}
+    _assert_batches_equal(stacked_node_batches(cfg, 4), _direct(cfg, 4))
+    assert pipeline_stats(cfg)["cold_starts"] == 1
+
+
+def test_input_programs_keep_their_module_name():
+    """The direct and the segment program both lower as ``jit__node_batches``,
+    the module name the benchmark's ``input_ms_per_step`` reads."""
+    cfg = DataConfig(vocab=64, seq_len=16, global_batch=4, n_shards=2, seed=2)
+    stacked_node_batches(cfg, 0)
+    p = pipeline._PIPELINES[(cfg, None)]
+    step, shards = jnp.int32(1), jnp.arange(2, dtype=jnp.int32)
+    for args in ((step, shards), (step, shards, p.factors, p.carry)):
+        text = pipeline._node_batches.lower(cfg, None, *args).as_text()
+        assert re.search(r"^module @jit__node_batches\b", text, re.M)
+
+
+@pytest.mark.parametrize("vocab", [17, 50280])
+def test_next_token_is_the_categorical_draw(vocab):
+    """The walk's draw is ``jax.random.categorical``'s, argmax ties included."""
+    e, f = pipeline._markov_factors(DataConfig(vocab=vocab, seq_len=8, global_batch=1))
+    tok = jnp.arange(5) * 3 % vocab
+    for i in range(4):
+        k = jax.random.key(i)
+        want = jax.random.categorical(k, e[tok] @ f.T)
+        np.testing.assert_array_equal(np.asarray(pipeline._next_token((e, f), tok, k)),
+                                      np.asarray(want))
+    # ties resolve to the lowest index, as argmax's do
+    flat = (jnp.zeros((vocab, 64)), jnp.zeros((vocab, 64)))
+    k = jax.random.key(0)
+    np.testing.assert_array_equal(
+        np.asarray(pipeline._next_token(flat, tok, k)),
+        np.asarray(jax.random.categorical(k, jnp.zeros((5, vocab)))))
 
 
 @pytest.mark.parametrize("env", [None, "elsewhere"])
