@@ -82,7 +82,7 @@ def causal_mask(S: int, window: Optional[int] = None) -> jax.Array:
     return m
 
 
-# Above this many query positions, self-attention runs in the chunked
+# At or above this many query positions, self-attention runs in the chunked
 # (online-softmax / "flash"-style) formulation: O(S * CHUNK) live memory instead
 # of the O(S^2) score tensor — required for the 32k prefill shapes, where the
 # materialized scores would be ~17 GB/chip/layer.
@@ -153,10 +153,11 @@ def gqa_forward(x: jax.Array, p: Params, *, n_heads: int, n_kv: int, head_dim: i
     q = apply_rope(_split_heads(dense(x, p["wq"]), n_heads), pos, theta)
     k = apply_rope(_split_heads(dense(x, p["wk"]), n_kv), pos, theta)
     v = _split_heads(dense(x, p["wv"]), n_kv)
-    if S >= FLASH_THRESHOLD:
-        out = _sdpa_chunked(q, k, v, window=window)
-    else:
-        out = _sdpa(q, k, v, causal_mask(S, window))
+    with jax.named_scope("attention"):
+        if S >= FLASH_THRESHOLD:
+            out = _sdpa_chunked(q, k, v, window=window)
+        else:
+            out = _sdpa(q, k, v, causal_mask(S, window))
     return dense(out.reshape(B, S, -1), p["wo"])
 
 
@@ -222,16 +223,18 @@ def mla_forward(x: jax.Array, p: Params, *, n_heads: int, kv_lora: int, qk_nope:
         q_eff = jnp.concatenate([q_nope, q_rope], axis=-1)
         k_eff = jnp.concatenate(
             [k_nope, jnp.broadcast_to(k_rope, (B, S, n_heads, qk_rope))], axis=-1)
-        out = _sdpa_chunked(q_eff, k_eff, v)
+        with jax.named_scope("attention"):
+            out = _sdpa_chunked(q_eff, k_eff, v)
         return dense(out.reshape(B, S, -1), p["wo"])
 
-    scale = 1.0 / jnp.sqrt(qk_nope + qk_rope)
-    s1 = jnp.einsum("bshd,bthd->bhst", q_nope, k_nope)
-    s2 = jnp.einsum("bshd,btxd->bhst", q_rope, k_rope)
-    scores = (s1 + s2).astype(jnp.float32) * scale
-    scores = jnp.where(causal_mask(S)[None, None], scores, NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-    out = jnp.einsum("bhst,bthd->bshd", probs, v)
+    with jax.named_scope("attention"):
+        scale = 1.0 / jnp.sqrt(qk_nope + qk_rope)
+        s1 = jnp.einsum("bshd,bthd->bhst", q_nope, k_nope)
+        s2 = jnp.einsum("bshd,btxd->bhst", q_rope, k_rope)
+        scores = (s1 + s2).astype(jnp.float32) * scale
+        scores = jnp.where(causal_mask(S)[None, None], scores, NEG_INF)
+        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+        out = jnp.einsum("bhst,bthd->bshd", probs, v)
     return dense(out.reshape(B, S, -1), p["wo"])
 
 
