@@ -51,6 +51,7 @@ from repro.distributed.gossip import make_gossip_plan
 from repro.distributed.wire import make_wire_format
 from repro.launch.mesh import node_mesh
 from repro.models.api import build_model
+from repro.models.attention import attention_stats
 from repro.optim import make_optimizer
 from repro.optim.schedules import linear_warmup_cosine
 
@@ -198,6 +199,8 @@ def run_training(cfg: ArchConfig, tc: TrainConfig,
         tc0 = time.time()
         hist["compiled_steps"].append(step_fn.lower(state, batch).compile())
         hist["compile_s"].append(time.time() - tc0)
+        if len(hist["compiled_steps"]) == 1:
+            print(f"attention cores: {attention_stats()}", flush=True)
         ran_steps = 0
         for t in range(first, seg_stop):
             # host spans on the profiler's clock, named as the benchmark's

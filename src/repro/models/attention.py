@@ -9,16 +9,24 @@ All functions are pure; KV caches are NamedTuple pytrees so they thread through
 
 Keys are stored *already roped at absolute positions*; RoPE's relative property
 makes ring-buffer overwrites safe.
+
+Training / prefill self-attention (``gqa_forward``) picks its core by
+``core_path``: on a TPU the Pallas flash kernel (``kernels/attention.py``),
+elsewhere the dense ``_sdpa`` or, for long sequences, ``_sdpa_chunked``.
+``_sdpa`` is also the plain reference the kernel is tested against.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 from functools import partial
-from typing import NamedTuple, Optional
+from typing import Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import attention as flash
+from repro.kernels import ops as kops
 from repro.models.layers import COMPUTE_DTYPE, Params, apply_rope, dense, dense_init
 
 NEG_INF = -1e9
@@ -144,6 +152,34 @@ def _sdpa_chunked(q, k, v, *, window: Optional[int] = None,
     return out.transpose(0, 3, 1, 2, 4).reshape(B, S, H, Dv).astype(q.dtype)
 
 
+# attention cores traced by ``gqa_forward``, by path (``attention_stats``)
+_CORES: collections.Counter = collections.Counter()
+
+
+def core_path(seq_len: int, groups: int, head_dim: int, window: Optional[int]) -> str:
+    """Which implementation ``gqa_forward`` traces for its core: ``chunked``
+    (``_sdpa_chunked``) from ``FLASH_THRESHOLD`` up; ``kernel`` (the Pallas
+    flash kernel, ``kernels/attention.py``) for an unwindowed causal core
+    whose length, query heads per key/value head and head size the kernel
+    takes, where the kernels compile with Mosaic (a TPU, or a described TPU
+    with ``interpret_kernels`` patched to False); else ``dense`` (``_sdpa``),
+    on the CPU and on every other backend too.  All three compute the same
+    attention."""
+    if seq_len >= FLASH_THRESHOLD:
+        return "chunked"
+    if (window is None and flash.supports(seq_len, groups, head_dim)
+            and jax.default_backend() in ("cpu", "tpu") and not kops.interpret_kernels()):
+        return "kernel"
+    return "dense"
+
+
+def attention_stats() -> Dict[str, int]:
+    """How many attention cores ``gqa_forward`` has traced in this process,
+    by path.  A core traced inside a layer scan counts once, however many
+    layers or nodes run it."""
+    return {path: _CORES[path] for path in ("kernel", "dense", "chunked")}
+
+
 def gqa_forward(x: jax.Array, p: Params, *, n_heads: int, n_kv: int, head_dim: int,
                 theta: float, window: Optional[int] = None,
                 positions: Optional[jax.Array] = None) -> jax.Array:
@@ -153,8 +189,12 @@ def gqa_forward(x: jax.Array, p: Params, *, n_heads: int, n_kv: int, head_dim: i
     q = apply_rope(_split_heads(dense(x, p["wq"]), n_heads), pos, theta)
     k = apply_rope(_split_heads(dense(x, p["wk"]), n_kv), pos, theta)
     v = _split_heads(dense(x, p["wv"]), n_kv)
+    path = core_path(S, n_heads // n_kv, head_dim, window)
+    _CORES[path] += 1
     with jax.named_scope("attention"):
-        if S >= FLASH_THRESHOLD:
+        if path == "kernel":
+            out = flash.causal_gqa(q, k, v, interpret=False)
+        elif path == "chunked":
             out = _sdpa_chunked(q, k, v, window=window)
         else:
             out = _sdpa(q, k, v, causal_mask(S, window))

@@ -4,7 +4,9 @@ No chip is attached.  The TPU compiler installed beside jax compiles for a
 described ``v5e:2x2`` topology, so Mosaic's refusals — unsupported casts and
 reductions, scoped-VMEM overflows from too large a row tile — fail here, at
 the shapes the main path hands the kernels: the largest leaf of mamba2-370m
-(the 50,432 x 1,024 embedding, stacked over two gossip nodes).
+(the 50,432 x 1,024 embedding, stacked over two gossip nodes), and one
+granite-3-2b layer at its published widths for the attention kernel, whose
+core is also compiled at the widest head groups of the repo's models.
 
 The topology is described inside a module fixture, never while a module is
 imported: only one process at a time may load the TPU library, and every test
@@ -14,16 +16,23 @@ back without one.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
+from repro.kernels import attention as flash
 from repro.kernels import lowrank, quant
+from repro.kernels import ops as kops
+from repro.models import attention as attn
+from repro.models.api import build_model
 
 _CFG = get_config("mamba2-370m")
 _NODES = 2
@@ -115,3 +124,63 @@ def test_lowrank_axpy_compiles(one_chip, m, n):
     _compile(functools.partial(lowrank.lowrank_axpy_2d, weight=0.5,
                                acc_weight=0.5),
              one_chip, ((m, 2), _F32), ((n, 2), _F32), ((m, n), _F32))
+
+
+def test_attention_kernel_compiles_in_a_granite_layer(one_chip, monkeypatch):
+    """The gradient of one granite-3-2b layer (d 2048, 32 query over 8 KV
+    heads of 64, S 2048) with remat, vmapped over two nodes: its attention
+    core lowers to Mosaic kernels named under the ``attention`` scope in the
+    forward and the backward, and no S x S score tensor is left."""
+    monkeypatch.setattr(kops, "interpret_kernels", lambda: False)
+    nodes, seq = 2, 2048
+    cfg = dataclasses.replace(get_config("granite-3-2b"), n_layers=1, vocab=256)
+    assert (cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.hd) == (2048, 32, 8, 64)
+    model = build_model(cfg)
+    stacked = lambda l: jax.ShapeDtypeStruct((nodes,) + l.shape, l.dtype,  # noqa: E731
+                                             sharding=one_chip)
+    params = jax.tree.map(stacked, jax.eval_shape(model.init, jax.random.key(0)))
+    tokens = jax.ShapeDtypeStruct((nodes, 1, seq), jnp.int32, sharding=one_chip)
+    grad = jax.vmap(jax.grad(lambda p, t: model.loss(
+        p, {"tokens": t, "labels": t}, remat=True)[0]))
+    text = jax.jit(grad).lower(params, tokens).compile().as_text()
+
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line
+               and re.match(r"\s*%flash_attention_", line)]
+    names = [re.search(r'op_name="([^"]*)"', line).group(1) for line in kernels]
+    scoped = [n for n in names if "attention" in n.split("/")]
+    assert scoped == names and names, names
+    assert [n for n in scoped if "transpose(" not in n], "no forward kernel"
+    assert [n for n in scoped if "transpose(" in n], "no backward kernel"
+    # an S x S tensor of at least one head group's scores (4 heads)
+    for dims in re.findall(rf"(?:f32|bf16)\[([\d,]*{seq},{seq})\]", text):
+        assert np.prod([int(d) for d in dims.split(",")]) < 4 * seq * seq, dims
+
+
+@pytest.mark.parametrize("name,block", [("mistral-large-123b", 256), ("internvl2-76b", 512)])
+def test_attention_kernel_compiles_at_wide_head_groups(one_chip, monkeypatch, name, block):
+    """The attention core's remat gradient at a model's head widths (12 and 8
+    query heads of 128 a group), S 2048, vmapped over two nodes: it takes the
+    kernel at the tile ``block_size`` picks from the scoped VMEM, all three
+    kernels lower through Mosaic, and no S x S tensor is left."""
+    monkeypatch.setattr(kops, "interpret_kernels", lambda: False)
+    nodes, seq = 2, 2048
+    cfg = get_config(name)
+    groups = cfg.n_heads // cfg.n_kv_heads
+    assert attn.core_path(seq, groups, cfg.hd, None) == "kernel"
+    assert flash.block_size(seq, groups, cfg.hd) == block
+    core = jax.checkpoint(lambda q, k, v: flash.causal_gqa(q, k, v, interpret=False),
+                          policy=jax.checkpoint_policies.nothing_saveable)
+    grad = jax.grad(lambda q, k, v: jnp.sum(jax.vmap(core)(q, k, v).astype(jnp.float32)),
+                    argnums=(0, 1, 2))
+    q = jax.ShapeDtypeStruct((nodes, 1, seq, cfg.n_heads, cfg.hd), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((nodes, 1, seq, cfg.n_kv_heads, cfg.hd), jnp.bfloat16,
+                              sharding=one_chip)
+    text = jax.jit(grad).lower(q, kv, kv).compile().as_text()
+
+    kernels = {m for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line
+               for m in re.findall(r"flash_attention_(fwd|dkv|dq)", line.split("=")[0])}
+    assert kernels == {"fwd", "dkv", "dq"}, kernels
+    assert not re.search(rf"\[[\d,]*{seq},{seq}\]", text)
