@@ -49,6 +49,7 @@ call, not a fork of the runtime.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 from typing import Any, Callable, ClassVar, Dict, Optional, Tuple
@@ -63,7 +64,7 @@ from repro.kernels.quant import (
     pcg_hash,
     sparse_scatter_axpy_2d,
     uniform_from_hash,
-    unpack_dequant_axpy_2d,
+    unpack_dequant_axpy,
     unpack_sign_axpy_2d,
 )
 from repro.kernels.ref import (
@@ -404,36 +405,44 @@ class QuantWire(WireFormat):
         block = payload["codes"].shape[-1] * 32 // self.bits \
             if self.packed else payload["codes"].shape[-1]
         if self.packed and self._kernel_ok(block):
+            _DECODES["rows"] += 1
             return _fused_axpy_leaf(payload["codes"], payload["scale"], acc,
                                     bits=self.bits, weight=weight,
                                     acc_weight=acc_weight)
+        _DECODES["jnp"] += 1
         return super().decode_axpy(payload, acc, weight, acc_weight)
+
+
+# leaves traced by ``QuantWire.decode_axpy``, by path (``decode_stats``)
+_DECODES: collections.Counter = collections.Counter()
+
+
+def decode_stats() -> Dict[str, int]:
+    """How many leaves :meth:`QuantWire.decode_axpy` has traced in this
+    process, by path: ``rows`` through the fused kernel over the leaf's own
+    row view (:func:`_fused_axpy_leaf`), ``jnp`` on the base class's
+    reference path (the int8 container, or a block below the 128-lane gate).
+    A decode traced once counts once, however many nodes or steps run it."""
+    return {path: _DECODES[path] for path in ("rows", "jnp")}
 
 
 def _fused_axpy_leaf(codes: jax.Array, scale: jax.Array, acc: jax.Array, *,
                      bits: int, weight, acc_weight=1.0) -> jax.Array:
     """One leaf of :meth:`QuantWire.decode_axpy` through the fused kernel.
 
-    codes (lead..., nblk, W) uint32 + scale (lead..., nblk, 1) -> folded into a
-    (lead*nblk, block) 2-D view for the kernel; the leading (node) axis stays
-    outermost, so the fold preserves leading-dim sharding under shard_map."""
-    block = codes.shape[-1] * 32 // bits
-    nblk = codes.shape[-2]
-    lead = acc.shape[:-1]
-    orig_last = acc.shape[-1]
-    accf = acc.astype(jnp.float32)
-    pad = nblk * block - orig_last
-    if pad:
-        accf = jnp.pad(accf, [(0, 0)] * (accf.ndim - 1) + [(0, pad)])
-    rows = int(np.prod(lead, dtype=np.int64)) * nblk
-    out = unpack_dequant_axpy_2d(
-        codes.reshape(rows, codes.shape[-1]),
-        scale.reshape(rows, 1),
-        accf.reshape(rows, block),
+    acc (lead..., last), codes (lead..., nblk, W) uint32 and scale
+    (lead..., nblk, 1) go to the kernel with their leading dims merged into
+    rows — a bitcast of each, so nothing is copied around the call; the
+    leading (node) axis stays outermost, so the view preserves leading-dim
+    sharding under shard_map."""
+    rows = int(np.prod(acc.shape[:-1], dtype=np.int64))
+    nblk, w = codes.shape[-2:]
+    out = unpack_dequant_axpy(
+        codes.reshape(rows, nblk, w), scale.reshape(rows, nblk),
+        acc.reshape(rows, acc.shape[-1]),
         bits=bits, weight=weight, acc_weight=acc_weight,
         interpret=_kops.interpret_kernels())
-    out = out.reshape(*lead, nblk * block)[..., :orig_last]
-    return out.astype(acc.dtype)
+    return out.reshape(acc.shape).astype(acc.dtype)
 
 
 @dataclasses.dataclass(frozen=True)

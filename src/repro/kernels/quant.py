@@ -13,11 +13,13 @@ kernel families share one VMEM pass over the tensor:
   bytes, not a formula.
 
 Receive side mirrors it: ``unpack_dequant_2d`` (unpack -> dequantize) and
-``unpack_dequant_axpy_2d`` (unpack -> dequantize -> ``acc + w * value``), which
+``unpack_dequant_axpy`` (unpack -> dequantize -> ``acc + w * value``), which
 fuses the neighbor-mix accumulation so the reconstructed fp32 neighbor tensor is
-never materialized in HBM before the gossip average.  The axpy weight is a
-scalar *operand* (not a compile-time constant), so traced mixing weights —
-ECD's 2/s blend — drive the same kernel.
+never materialized in HBM before the gossip average.  It addresses a leaf
+through its own row view ``(rows, last)``, one grid step per (row tile,
+block), so the leaf is never copied into a ``(rows * nblk, block)`` view.
+The axpy weight is a scalar *operand* (not a compile-time constant), so
+traced mixing weights — ECD's 2/s blend — drive the same kernel.
 
 Packed wire format v2 — bit-exact stream layout (shared with kernels/ref.py
 and ``QuantWire`` in distributed/wire.py; all three produce identical
@@ -46,7 +48,8 @@ contiguous lane slices, so pack/unpack never needs a strided lane gather
 ``cpg``; ``cols % 128 == 0`` (the lane-width contract below) guarantees it.
 Tail handling lives one level up: callers pad the last dim to a whole block
 (``aligned_block`` rounds the block to whole groups) and slice ``[:n]`` after
-dequantize, so ragged tails never reach the kernels.
+dequantize, so ragged tails never reach the kernels; ``unpack_dequant_axpy``
+alone takes a ragged last block as a masked edge block.
 
 TPU adaptation notes (vs. a CUDA quantizer):
 * Blocks are *rows* of a (rows, block_size) view with block_size a multiple of 128
@@ -75,7 +78,7 @@ fields, no sign bias):
   w*val_i, 0)``) — a dense one-hot contraction, O(k*cols) VPU work, chosen
   over a real scatter because the TPU VPU has no cheap strided lane store;
   the axpy variant folds ``acc_weight * acc`` into the same pass exactly like
-  ``unpack_dequant_axpy_2d``.  Indices within a row are duplicate-free, so
+  ``unpack_dequant_axpy``.  Indices within a row are duplicate-free, so
   every lane receives at most one value and the accumulation order cannot
   change the result.
 
@@ -229,9 +232,20 @@ def _unpack_dequant_axpy_kernel(weights_ref, packed_ref, scale_ref, acc_ref, out
     # weights_ref = [acc_weight, weight]: out = acc_weight*acc + weight*dequant.
     # Scaling the accumulator here (rather than pre-scaling it in HBM) keeps
     # ECD's (1-2/s)*tilde + (2/s)*decode update a genuine single VMEM pass.
-    word = packed_ref[...]
+    # Grid (row tile, block b): the row tile's codes hold a group of up to 8
+    # blocks (one sublane tile) and its scales every block, so block b's
+    # words are a strided sublane load and its scale the one lane of b (a
+    # max over -inf elsewhere is exact).
+    scale = scale_ref[...]
+    if packed_ref.ndim == 3:
+        b = pl.program_id(1)
+        word = packed_ref[:, b % packed_ref.shape[1], :]
+        lane = jax.lax.broadcasted_iota(jnp.int32, scale.shape, 1)
+        scale = jnp.max(jnp.where(lane == b, scale, -jnp.inf), axis=1, keepdims=True)
+    else:
+        word = packed_ref[...]
     aw = weights_ref[0]
-    inv = scale_ref[...] * (weights_ref[1] * jnp.float32(1.0 / levels))
+    inv = scale * (weights_ref[1] * jnp.float32(1.0 / levels))
     cpg, wpg = stream_geometry(bits)
     g = word.shape[-1] // wpg
     for j, u in _unpacked_planes(word, bits=bits, levels=levels):
@@ -404,10 +418,10 @@ def unpack_dequant_2d(packed: jax.Array, scale: jax.Array, *, bits: int,
     return out[:rows] if pad else out
 
 
-def unpack_dequant_axpy_2d(packed: jax.Array, scale: jax.Array, acc: jax.Array, *,
-                           bits: int, weight, acc_weight=1.0,
-                           interpret: bool = False) -> jax.Array:
-    """Fused unpack + dequantize + accumulate:
+def unpack_dequant_axpy(packed: jax.Array, scale: jax.Array, acc: jax.Array, *,
+                        bits: int, weight, acc_weight=1.0,
+                        interpret: bool = False) -> jax.Array:
+    """Fused unpack + dequantize + accumulate over a leaf's own row view:
     ``acc_weight * acc + weight * dequant(packed)``.
 
     The receive side of a gossip round: the reconstructed fp32 neighbor never
@@ -416,34 +430,64 @@ def unpack_dequant_axpy_2d(packed: jax.Array, scale: jax.Array, acc: jax.Array, 
     traced scalars (they ride a (2,) operand, like the seed on the send side);
     ``acc_weight`` serves ECD's ``(1-2/s)*tilde + (2/s)*decode`` update
     without pre-scaling the accumulator through HBM.
+
+    ``acc`` (rows, last) is the leaf with its leading dims merged, and
+    ``packed`` (rows, nblk, W) uint32 / ``scale`` (rows, nblk) its payload
+    the same way: ``nblk = ceil(last / block)`` blocks of ``block = W*32/bits``
+    along the last dim.  Merging leading dims is a bitcast on a TPU, so no
+    operand and no result is copied into another layout.  The grid runs over
+    (row tiles, blocks); a row tile's codes are fetched 8 blocks at a time.
+    A last block that runs past ``last`` is Pallas's masked edge block (its
+    lanes past ``last`` are read as don't-care and never written), as are a
+    last group of fewer than 8 blocks and a last row tile past ``rows``.
     """
-    rows, w = packed.shape
+    rows, last = acc.shape
+    _, nblk, w = packed.shape
     assert bits in PACKABLE_BITS
     levels = 2 ** (bits - 1) - 1
-    cols = w * 32 // bits
-    assert acc.shape == (rows, cols), (acc.shape, (rows, cols))
-    bm = _pick_block_rows(rows, [(w, jnp.uint32), (1, jnp.float32),
-                                 (cols, jnp.float32), (cols, jnp.float32)],
+    block = w * 32 // bits
+    assert packed.shape[0] == rows and nblk == -(-last // block), (packed.shape, acc.shape)
+    assert scale.shape == (rows, nblk), (scale.shape, (rows, nblk))
+    # codes of a group of nb blocks a row tile, (bm, nb, W) in one (8, 128)
+    # tile of sublanes, so the tile's VMEM does not grow with nblk
+    nb = min(nblk, 8)
+    code_cols = w if nblk == 1 else 8 * (-(-w // 128) * 128)
+    bm = _pick_block_rows(rows, [(code_cols, jnp.uint32), (nblk, jnp.float32),
+                                 (block, jnp.float32), (block, jnp.float32)],
                           temps=[w])
-    (packed, scale, acc), pad = _pad_rows([packed, scale, acc], bm, rows)
-    grid = ((rows + pad) // bm,)
+    if nblk == 1:
+        packed = packed.reshape(rows, w)
+        words = pl.BlockSpec((bm, w), lambda i, j: (i, 0))
+    else:
+        words = pl.BlockSpec((bm, nb, w), lambda i, j: (i, j // nb, 0))
     weights = jnp.stack([jnp.asarray(acc_weight, jnp.float32).reshape(()),
                          jnp.asarray(weight, jnp.float32).reshape(())])
-    out = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_unpack_dequant_axpy_kernel, bits=bits, levels=levels),
         name="unpack_dequant_axpy",
-        grid=grid,
+        grid=(pl.cdiv(rows, bm), nblk),
         in_specs=[
-            pl.BlockSpec((2,), lambda i: (0,)),  # [acc_weight, weight], broadcast
-            pl.BlockSpec((bm, w), lambda i: (i, 0)),
-            pl.BlockSpec((bm, 1), lambda i: (i, 0)),
-            pl.BlockSpec((bm, cols), lambda i: (i, 0)),
+            pl.BlockSpec((2,), lambda i, j: (0,)),  # [acc_weight, weight], broadcast
+            words,
+            pl.BlockSpec((bm, nblk), lambda i, j: (i, 0)),
+            pl.BlockSpec((bm, block), lambda i, j: (i, j)),
         ],
-        out_specs=pl.BlockSpec((bm, cols), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows + pad, cols), jnp.float32),
+        out_specs=pl.BlockSpec((bm, block), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((rows, last), jnp.float32),
         interpret=interpret,
     )(weights, packed, scale.astype(jnp.float32), acc.astype(jnp.float32))
-    return out[:rows] if pad else out
+
+
+def unpack_dequant_axpy_2d(packed: jax.Array, scale: jax.Array, acc: jax.Array, *,
+                           bits: int, weight, acc_weight=1.0,
+                           interpret: bool = False) -> jax.Array:
+    """:func:`unpack_dequant_axpy` of a (rows, cols) view whose every row is
+    one block: ``packed`` (rows, cols*bits/32), ``scale`` (rows, 1)."""
+    rows, w = packed.shape
+    cols = w * 32 // bits
+    assert acc.shape == (rows, cols), (acc.shape, (rows, cols))
+    return unpack_dequant_axpy(packed[:, None], scale, acc, bits=bits, weight=weight,
+                               acc_weight=acc_weight, interpret=interpret)
 
 
 # --------------------------------------------------------------- sparse codec

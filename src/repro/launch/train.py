@@ -49,7 +49,7 @@ from repro.distributed.decentralized import (
 from repro.analysis.jaxpr_checks import jit_compile_count
 from repro.distributed.failures import make_drop_spec
 from repro.distributed.gossip import make_gossip_plan
-from repro.distributed.wire import make_wire_format
+from repro.distributed.wire import decode_stats, make_wire_format
 from repro.launch.mesh import node_mesh
 from repro.models.api import build_model
 from repro.models.attention import attention_stats
@@ -200,6 +200,7 @@ def run_training(cfg: ArchConfig, tc: TrainConfig,
         hist["compile_s"].append(time.time() - tc0)
         if len(hist["compiled_steps"]) == 1:
             print(f"attention cores: {attention_stats()}", flush=True)
+            print(f"decode-axpy leaves: {decode_stats()}", flush=True)
         ran_steps = 0
         for t in range(first, seg_stop):
             # host spans on the profiler's clock, named as the benchmark's

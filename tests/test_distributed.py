@@ -553,6 +553,33 @@ def test_dist_step_uses_fused_axpy_kernel(algo):
         expected_kernel_calls(algo, plan, wire, small.params) == 0
 
 
+@pytest.mark.parametrize("algo", ["dcd", "ecd"])
+def test_decode_stats_counts_traced_leaves_by_path(algo):
+    """``decode_stats`` counts each leaf a traced step decodes, at every
+    decode site: the 2048-wide leaf through the fused kernel over its row
+    view (``rows``; the interpret kernel on the CPU), the 8-wide one, below
+    the 128-lane gate, on the jnp path."""
+    from repro.analysis.jaxpr_checks import decode_sites
+    from repro.distributed.wire import decode_stats
+
+    n, d = 4, 2048
+    plan = make_gossip_plan("ring", n)
+
+    def loss(p, batch):
+        return _toy_loss(p["w"], batch)[0] + jnp.mean(p["b"]), {}
+
+    step = make_dist_train_step(loss, algo, sgd(), QuantWire(bits=4, block=1024),
+                                plan, constant(0.05))
+    state = init_dist_state(algo, {"w": jnp.zeros((d,)), "b": jnp.zeros((8,))},
+                            plan, sgd())
+    before = decode_stats()
+    jax.make_jaxpr(step)(state, _toy_batch(jax.random.key(0), n, d=d))
+    after = decode_stats()
+    sites = decode_sites(algo, plan)
+    assert sites >= 2
+    assert {k: after[k] - before[k] for k in after} == {"rows": sites, "jnp": sites}
+
+
 def test_wire_codec_3bit_measured_bits_per_element():
     """Acceptance: bits=3, block=1024 — the stacked payload the ring step rolls
     ships <= 3.2 wire bits/element, measured from real payload nbytes."""

@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+from repro.distributed.wire import QuantWire
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
 from repro.kernels.quant import (
@@ -12,6 +13,7 @@ from repro.kernels.quant import (
     quantize_2d,
     quantize_pack_2d,
     unpack_dequant_2d,
+    unpack_dequant_axpy,
     unpack_dequant_axpy_2d,
 )
 
@@ -140,6 +142,59 @@ def test_unpack_dequant_kernels_match_ref(bits):
     ax_r = kref.unpack_dequant_axpy_2d_ref(packed, scale, acc, bits=bits, weight=1 / 3)
     np.testing.assert_allclose(np.asarray(ax_k), np.asarray(ax_r),
                                rtol=1e-5, atol=1e-6)
+
+
+def _folded_axpy(codes, scale, acc, *, bits, weight, acc_weight):
+    """The decode-axpy through a (rows * nblk, block) fold of the leaf: pad
+    the last dim to whole blocks, one kernel row per block, unfold."""
+    block = codes.shape[-1] * 32 // bits
+    nblk, lead, last = codes.shape[-2], acc.shape[:-1], acc.shape[-1]
+    accf = jnp.pad(acc, [(0, 0)] * len(lead) + [(0, nblk * block - last)])
+    rows = int(np.prod(lead)) * nblk
+    args = (codes.reshape(rows, -1), scale.reshape(rows, 1), accf.reshape(rows, block))
+    out = unpack_dequant_axpy_2d(*args, bits=bits, weight=weight,
+                                 acc_weight=acc_weight, interpret=True)
+    ref = kref.unpack_dequant_axpy_2d_ref(*args, bits=bits, weight=weight,
+                                          acc_weight=acc_weight)
+    unfold = lambda o: o.reshape(*lead, nblk * block)[..., :last]  # noqa: E731
+    return unfold(out), unfold(ref)
+
+
+@pytest.mark.parametrize("bits,shape,traced", [
+    (4, (16, 1024), False),          # nblk == 1: the folded view itself
+    (4, (2, 3, 8, 4096), False),     # nblk 4 on a stacked 4-D leaf
+    (4, (2, 8, 1280), False),        # a partial last block: 1280 = 1024 + 256
+    (4, (24, 2048), False),          # a 2-D leaf
+    (4, (2, 8, 3072), True),         # traced weight and acc_weight (ECD)
+    (3, (2, 8, 2560), False),        # 3-bit: codes straddle words; partial block
+    (4, (2, 8, 11520), False),       # nblk 12: codes fetched 8 blocks, then 4
+], ids=["nblk1", "stacked4d", "partial", "2d", "traced", "3bit", "groups"])
+def test_rows_view_decode_axpy_matches_folded_and_oracle(bits, shape, traced):
+    """The decode-axpy over the leaf's own (rows, last) view equals the fold
+    into (rows * nblk, block) bit for bit, and the kernels/ref.py oracle to
+    its rounding (the oracle multiplies the weight in last)."""
+    x = jax.random.normal(jax.random.key(11), shape) * 2.0
+    acc = jax.random.normal(jax.random.key(12), shape)
+    payload = QuantWire(bits=bits, block=1024).encode(x, jnp.uint32(4321))
+    codes, scale = payload["codes"], payload["scale"]
+    rows, (nblk, w) = int(np.prod(shape[:-1])), codes.shape[-2:]
+
+    def rows_view(c, s, a, wt, aw):
+        out = unpack_dequant_axpy(c.reshape(rows, nblk, w), s.reshape(rows, nblk),
+                                  a.reshape(rows, shape[-1]), bits=bits, weight=wt,
+                                  acc_weight=aw, interpret=True)
+        return out.reshape(shape)
+
+    weights = (jnp.float32(0.4), jnp.float32(0.6)) if traced else (1 / 3, 1.0)
+    if traced:
+        got = jax.jit(rows_view)(codes, scale, acc, *weights)
+    else:
+        got = rows_view(codes, scale, acc, *weights)
+    folded, oracle = _folded_axpy(codes, scale, acc, bits=bits, weight=weights[0],
+                                  acc_weight=weights[1])
+    np.testing.assert_array_equal(np.asarray(got).view(np.uint32),
+                                  np.asarray(folded).view(np.uint32))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(oracle), rtol=1e-5, atol=1e-6)
 
 
 @pytest.mark.parametrize("bits", [2, 4])

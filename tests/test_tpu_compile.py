@@ -27,12 +27,18 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.analysis.jaxpr_checks import decode_sites
 from repro.configs import get_config
+from repro.distributed import wire as wires
+from repro.distributed.decentralized import init_dist_state, make_dist_train_step
+from repro.distributed.gossip import make_gossip_plan
 from repro.kernels import attention as flash
 from repro.kernels import lowrank, quant
 from repro.kernels import ops as kops
 from repro.models import attention as attn
 from repro.models.api import build_model
+from repro.optim import make_optimizer
+from repro.optim.schedules import constant
 
 _CFG = get_config("mamba2-370m")
 _NODES = 2
@@ -96,11 +102,16 @@ def test_sign_pack_compiles(one_chip):
 
 
 @pytest.mark.parametrize("bits", [4, 3])
-def test_unpack_dequant_axpy_compiles(one_chip, bits):
-    _compile(functools.partial(quant.unpack_dequant_axpy_2d, bits=bits,
+@pytest.mark.parametrize("rows,last", [(_ROWS, 1024), (_NODES * _CFG.d_model, _VOCAB)],
+                         ids=["embed", "lm_head"])
+def test_unpack_dequant_axpy_compiles(one_chip, bits, rows, last):
+    """The embedding's rows are one block each; the head's rows are 50
+    blocks, the last a partial edge block (50,432 = 49 x 1024 + 256)."""
+    nblk = -(-last // 1024)
+    _compile(functools.partial(quant.unpack_dequant_axpy, bits=bits,
                                weight=0.5, acc_weight=0.5),
-             one_chip, ((_ROWS, 1024 * bits // 32), _U32), ((_ROWS, 1), _F32),
-             ((_ROWS, 1024), _F32))
+             one_chip, ((rows, nblk, 1024 * bits // 32), _U32), ((rows, nblk), _F32),
+             ((rows, last), _F32))
 
 
 def test_sparse_scatter_axpy_compiles(one_chip):
@@ -184,3 +195,47 @@ def test_attention_kernel_compiles_at_wide_head_groups(one_chip, monkeypatch, na
                for m in re.findall(r"flash_attention_(fwd|dkv|dq)", line.split("=")[0])}
     assert kernels == {"fwd", "dkv", "dq"}, kernels
     assert not re.search(rf"\[[\d,]*{seq},{seq}\]", text)
+
+
+_RELAYOUT = re.compile(r"^\s*(?:ROOT\s+)?%(\S+)\s*=\s*f32\[([\d,]*)\]\S*\s+"
+                       r"(reshape|pad|copy|transpose|fusion)\(.*op_name=\"([^\"]*)\"")
+
+
+def test_granite_step_decodes_each_leaf_in_its_own_layout(one_chip, monkeypatch):
+    """A DCD ``quant:4`` step over one granite-3-2b layer at its published
+    widths and a 6,400-row vocabulary (the head's last block is partial),
+    two nodes on a ring, compiled for a described v5e: every leaf decodes
+    through the fused kernel over its own row view, so no f32 array of a
+    kernel leaf's size, or of its size padded to whole blocks, is reshaped,
+    padded or copied under the ``decode`` scope, and ``decode_stats``
+    counts each traced decode as ``rows``."""
+    monkeypatch.setattr(kops, "interpret_kernels", lambda: False)
+    nodes, seq = 2, 512
+    cfg = dataclasses.replace(get_config("granite-3-2b"), n_layers=1, vocab=6400)
+    model = build_model(cfg)
+    plan = make_gossip_plan("ring", nodes)
+    wire = wires.make_wire_format("quant:4")
+    opt = make_optimizer("adamw")
+    step = make_dist_train_step(lambda p, b: model.loss(p, b, remat=True), "dcd", opt,
+                                wire, plan, constant(1e-4))
+    state = jax.eval_shape(lambda k: init_dist_state("dcd", model.init(k), plan, opt,
+                                                     wire=wire), jax.random.key(0))
+    state = jax.tree.map(lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype,
+                                                        sharding=one_chip), state)
+    tokens = jax.ShapeDtypeStruct((nodes, 1, seq), jnp.int32, sharding=one_chip)
+    before = wires.decode_stats()
+    text = jax.jit(step).lower(state, {"tokens": tokens, "labels": tokens}).compile().as_text()
+    after = wires.decode_stats()
+
+    leaves = jax.tree.leaves(state.params)
+    blocks = [wire._block_for(l.shape[-1]) for l in leaves]
+    sizes = {l.size for l in leaves} | {l.size // l.shape[-1] * -(-l.shape[-1] // b) * b
+                                        for l, b in zip(leaves, blocks)}
+    traced = decode_sites("dcd", plan) * len(leaves)
+    assert {k: after[k] - before[k] for k in after} == {"rows": traced, "jnp": 0}
+    assert len(re.findall(r"%unpack_dequant_axpy[.\d]* = ", text)) == traced
+    copies = [m.group(0)[:160] for m in map(_RELAYOUT.match, text.splitlines())
+              if m and "decode" in m.group(4).split("/")
+              and (m.group(3) != "fusion" or re.match(r"(copy|transpose)", m.group(1)))
+              and np.prod([int(d) for d in m.group(2).split(",") if d]) in sizes]
+    assert not copies, copies
