@@ -32,16 +32,37 @@ from repro.models.api import build_model  # noqa: E402
 from repro.optim import make_optimizer  # noqa: E402
 from repro.optim.schedules import linear_warmup_cosine  # noqa: E402
 
+def _replace_at(obj, path: str, value):
+    """``obj`` with the field at the dotted ``path`` set to ``value``; each
+    nested frozen dataclass on the way is rebuilt by ``dataclasses.replace``."""
+    head, _, rest = path.partition(".")
+    if rest:
+        value = _replace_at(getattr(obj, head), rest, value)
+    return dataclasses.replace(obj, **{head: value})
+
+
+def field_at(obj, path: str):
+    for part in path.split("."):
+        obj = getattr(obj, part)
+    return obj
+
+
 def arch_config(cfg: Dict[str, Any]):
-    """The program's ArchConfig for a configuration file, checked against it:
-    each of the file's ``program_fields`` (its key -> the program's field)
-    must hold the file's size."""
+    """The program's ArchConfig for a configuration file, checked against it.
+
+    Each ``reduced`` key is set through its ``program_fields`` path (dotted
+    for a nested group: ``ssm.n_heads``, ``moe.n_routed``); then each of the
+    file's ``program_fields`` (its key -> the program's field) must hold the
+    file's size."""
     arch = get_config(cfg["program_arch"])
-    arch = dataclasses.replace(arch, **{k: cfg[k] for k in cfg["reduced"]})
-    for key, attr in cfg["program_fields"].items():
-        got = arch
-        for part in attr.split("."):
-            got = getattr(got, part)
+    fields = cfg["program_fields"]
+    for key in cfg["reduced"]:
+        if key not in fields:
+            raise ValueError(f"{cfg['name']}: reduced key {key!r} has no program_fields "
+                             f"entry, so the program's size cannot be cut to it")
+        arch = _replace_at(arch, fields[key], cfg[key])
+    for key, attr in fields.items():
+        got = field_at(arch, attr)
         if got != cfg[key]:
             raise ValueError(f"{cfg['name']}: the program's {attr} is {got}, "
                              f"the configuration file says {key}={cfg[key]}")

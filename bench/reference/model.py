@@ -4,13 +4,24 @@ Written from the published descriptions and from the configuration files under
 ``bench/configs``, in straightforward ``jax.numpy``: no kernels, no chunked
 scan, no mixed precision.  It imports nothing of the program under test.
 
-This module holds what every family shares: the embedding, a stack of
-blocks, a final RMSNorm, an untied LM head and the mean token cross-entropy.
-A family's block is ``bench/reference/families/<family>.py`` (``block_init``
-and ``block``), found by the configuration's ``family``: ``ssm`` (Mamba-2),
-``dense`` (a Llama-style decoder).  Departures from the published models
-that the program makes, and that the reference therefore makes too, are
-listed in each configuration file under ``departures``.
+A configuration's ``family`` names its module,
+``bench/reference/families/<family>.py``, which plugs in one of two ways:
+
+* with a block alone (``block_init`` and ``block``): ``init_params`` and
+  ``sequence_loss`` below make the shared model around it, the embedding, a
+  uniform stack of ``n_layers`` blocks, a final RMSNorm, an untied LM head
+  and the mean token cross-entropy (``ssm``, Mamba-2; ``dense``, a
+  Llama-style decoder);
+* with a model of its own: a family that defines ``init_params`` and
+  ``sequence_loss`` (same signatures) owns its parameter tree and its loss,
+  and composes them from the public helpers here (``head_params``,
+  ``stack_init``, ``embed``, ``scan_blocks``, ``head_loss``) and from other
+  families' blocks: a layer pattern, a loop over the stack, leading layers of
+  another kind, a shared block (``hybrid``).
+
+Departures from the published models that the program makes, and that the
+reference therefore makes too, are listed in each configuration file under
+``departures``.
 
 ``Numerics`` holds the one knob: how matmul operands are rounded.  The
 reference leaves them in float32 and multiplies at ``HIGHEST`` precision; the
@@ -88,17 +99,29 @@ def family(cfg: Dict[str, Any]):
     return importlib.import_module(f"bench.reference.families.{cfg['family']}")
 
 
-def init_params(cfg: Dict[str, Any], key: jax.Array) -> Dict[str, Any]:
-    ks = jax.random.split(key, 8)
+def head_params(cfg: Dict[str, Any], ks) -> Dict[str, Any]:
+    """The embedding, the final norm and the untied head, from the first two
+    of the model's eight keys ``ks``."""
     d, vp = cfg["d_model"], padded_vocab(cfg)
-    layer_keys = jax.random.split(ks[6], cfg["n_layers"])
-    block = family(cfg).block_init
     return {
         "embed": normal(ks[0], (vp, d), 0.02),
         "final_ln": jnp.ones((d,), jnp.float32),
         "lm_head": normal(ks[1], (d, vp), 0.02),
-        "blocks": jax.vmap(lambda k: block(cfg, k))(layer_keys),
     }
+
+
+def stack_init(cfg: Dict[str, Any], key: jax.Array, n: int, block_init) -> Any:
+    """``n`` blocks of ``block_init``, stacked on a leading axis."""
+    return jax.vmap(lambda k: block_init(cfg, k))(jax.random.split(key, n))
+
+
+def init_params(cfg: Dict[str, Any], key: jax.Array) -> Dict[str, Any]:
+    fam = family(cfg)
+    if hasattr(fam, "init_params"):
+        return fam.init_params(cfg, key)
+    ks = jax.random.split(key, 8)
+    return dict(head_params(cfg, ks),
+                blocks=stack_init(cfg, ks[6], cfg["n_layers"], fam.block_init))
 
 
 # ----------------------------------------------------------------- layers
@@ -107,19 +130,37 @@ def rmsnorm(x, g, eps=1e-6):
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * g
 
 
-def sequence_loss(cfg: Dict[str, Any], params, tokens, labels, nx: Numerics):
-    """Mean next-token cross-entropy of one sequence (tokens, labels: (S,)).
+def embed(params, tokens, nx: Numerics):
+    """The embedded tokens (S, d), held as the program holds them."""
+    return nx.op(params["embed"][tokens])
 
-    The logits span the padded vocabulary, as the program's head does."""
-    block = family(cfg).block
-    h = nx.op(params["embed"][tokens])
 
+def scan_blocks(cfg: Dict[str, Any], h, blocks, block, nx: Numerics):
+    """``h`` through a stack of ``block``s, each recomputed in the backward
+    pass and its output held as the program holds it."""
     def layer(hh, lp):
         return nx.op(block(cfg, hh, lp, nx)), None
 
-    h, _ = jax.lax.scan(jax.checkpoint(layer), h, params["blocks"])
+    h, _ = jax.lax.scan(jax.checkpoint(layer), h, blocks)
+    return h
+
+
+def head_loss(params, h, labels, nx: Numerics):
+    """Final RMSNorm, the untied head over the padded vocabulary, and the
+    mean cross-entropy of ``labels``."""
     h = rmsnorm(h, params["final_ln"])
     logits = nx.mm("sd,dv->sv", h, params["lm_head"])
     logz = jax.nn.logsumexp(logits, axis=-1)
     gold = jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0]
     return jnp.mean(logz - gold)
+
+
+def sequence_loss(cfg: Dict[str, Any], params, tokens, labels, nx: Numerics):
+    """Mean next-token cross-entropy of one sequence (tokens, labels: (S,)).
+
+    The logits span the padded vocabulary, as the program's head does."""
+    fam = family(cfg)
+    if hasattr(fam, "sequence_loss"):
+        return fam.sequence_loss(cfg, params, tokens, labels, nx)
+    h = scan_blocks(cfg, embed(params, tokens, nx), params["blocks"], fam.block, nx)
+    return head_loss(params, h, labels, nx)

@@ -38,17 +38,29 @@ def _applies(metric: Dict[str, Any], workload: str) -> bool:
     return "workloads" not in metric or workload in metric["workloads"]
 
 
-def cell(bench: Dict[str, Any], workload: str) -> Dict[str, Any]:
-    """Everything one cell needs, read from its files."""
+def cell_files(bench: Dict[str, Any], workload: str) -> Dict[str, Any]:
+    """A cell's chips and the paths of its configuration, traffic and limits
+    files."""
     entry = {w["name"]: w for w in bench["workloads"]}.get(workload)
     if entry is None:
         raise KeyError(f"no workload {workload!r} in BENCHMARK.json")
     conf = {c["name"]: c for c in bench["configs"]}[entry["config"]]
     return {
         "chips": entry["chips"],
-        "config": _json(os.path.join(ROOT, conf["file"])),
-        "traffic": _json(os.path.join(BENCH_DIR, "workloads", f"{entry['traffic']}.json")),
-        "limits": _json(os.path.join(BENCH_DIR, "limits", f"{workload}.json"))["limits"],
+        "config": os.path.join(ROOT, conf["file"]),
+        "traffic": os.path.join(BENCH_DIR, "workloads", f"{entry['traffic']}.json"),
+        "limits": os.path.join(BENCH_DIR, "limits", f"{workload}.json"),
+    }
+
+
+def cell(bench: Dict[str, Any], workload: str) -> Dict[str, Any]:
+    """Everything one cell needs, read from its files."""
+    files = cell_files(bench, workload)
+    return {
+        "chips": files["chips"],
+        "config": _json(files["config"]),
+        "traffic": _json(files["traffic"]),
+        "limits": _json(files["limits"])["limits"],
         "end_to_end": [m for m in bench["end_to_end"] if _applies(m, workload)],
         "per_layer": [m for m in bench["per_layer"] if _applies(m, workload)],
     }

@@ -1,6 +1,7 @@
 """The comparison that decides ``correct`` fails what it must fail.
 
-At the program's reduced widths on the CPU, against each cell's own limits:
+At the program's reduced widths on the CPU, against each cell's own limits,
+for every cell ``tiny.cells`` rehearses:
 the control (the plain reference with float8 matmul operands, put in the
 program's place) and the faults a training cell can have, planted in the
 program underneath a whole run: a step that returns its state unchanged;
@@ -24,7 +25,7 @@ from bench.reference.train import ReferenceRun
 from bench.tests import tiny
 from bench.tests.test_cells_cpu import ROOT, run_small
 
-STACKED = [tiny.SSM_CELL, tiny.DENSE_CELL]
+STACKED = tiny.cells(chips=1)
 
 
 @pytest.mark.parametrize("workload", STACKED)
@@ -83,7 +84,7 @@ import repro.distributed.decentralized as dec
 dec._roll = lambda tree, shift: tree          # every permute left out
 from bench import harness, spec
 from bench.tests import tiny
-w = tiny.FOUR_CHIP_CELL
+w = sys.argv[2]
 bench, cfg, arch, traffic = tiny.small(w)
 spec.cell = tiny.patched_cell(w, traffic)
 res = harness.run_cell(w, 2 ** 33 + 9, 1.0, False, t_start=time.perf_counter(),
@@ -92,7 +93,7 @@ print(json.dumps(res))
 """
 
 
-@pytest.mark.parametrize("workload", STACKED + [tiny.FOUR_CHIP_CELL])
+@pytest.mark.parametrize("workload", STACKED + tiny.cells(chips=4))
 def test_exchange_between_chips_left_out_is_not_correct(monkeypatch, workload):
     if workload in STACKED:
         import repro.distributed.decentralized as dec
@@ -103,7 +104,7 @@ def test_exchange_between_chips_left_out_is_not_correct(monkeypatch, workload):
         return
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4")
-    out = subprocess.run([sys.executable, "-c", NO_EXCHANGE, ROOT], env=env,
+    out = subprocess.run([sys.executable, "-c", NO_EXCHANGE, ROOT, workload], env=env,
                          capture_output=True, text=True, timeout=900)
     assert out.returncode == 0, out.stderr[-3000:]
     res = json.loads(out.stdout.strip().splitlines()[-1])
@@ -131,5 +132,5 @@ def test_feed_altered_where_it_is_produced_is_not_correct(monkeypatch, fault):
     real = program.Cell.batch
     monkeypatch.setattr(program.Cell, "batch",
                         lambda self, seed, t: fault(real(self, seed, t)))
-    res = run_small(monkeypatch, tiny.SSM_CELL, False)
+    res = run_small(monkeypatch, STACKED[0], False)
     assert not res["correct"], res["checks"]

@@ -13,22 +13,13 @@ from bench import trace as T
 from bench.tests import tiny
 
 SEED = 2 ** 33 + 15         # over 32 bits, as a benchmark run's seeds may be
-CELL = tiny.DENSE_CELL
+CELL = "granite-L4.dcd-q4.stack2"
+MAMBA = "mamba2-L24.dcd-q4.stack2"
 
 
 def _json(path):
     with open(os.path.join(spec.ROOT, path)) as f:
         return json.load(f)
-
-
-def test_cell_files_are_the_rehearsal_files():
-    cfg_file, traffic_file, chips = tiny.CELLS[CELL]
-    got = spec.cell(spec.load_benchmark(), CELL)
-    assert got["config"] == _json(cfg_file)
-    assert got["traffic"] == _json(traffic_file)
-    assert got["chips"] == chips == 1
-    names = {m["name"] for m in got["per_layer"]}
-    assert {"attention_ms_per_step", "attention_core_roofline"} <= names
 
 
 def test_limits_lie_between_the_readings_of_the_calibration():
@@ -58,7 +49,9 @@ def test_program_and_reference_agree_at_a_small_size(monkeypatch):
     rehearsal, through the cell's check under the small limits."""
     _, cfg, arch, small_traffic = tiny.small(CELL)
     real = spec.cell(spec.load_benchmark(), CELL)
-    limits = _json(tiny.SMALL_LIMITS)[CELL]["limits"]
+    assert {"attention_ms_per_step", "attention_core_roofline"} <= \
+        {m["name"] for m in real["per_layer"]}
+    limits = tiny.small_limits(CELL)
     traffic = dict(real["traffic"], **{k: small_traffic[k] for k in
                                        ("seq_len", "seqs_per_node", "warm_steps",
                                         "trace_steps")})
@@ -85,7 +78,7 @@ def _compiled_names(workload):
     return scopes.op_names(text)
 
 
-@pytest.mark.parametrize("workload,named", [(CELL, True), (tiny.SSM_CELL, False)])
+@pytest.mark.parametrize("workload,named", [(CELL, True), (MAMBA, False)])
 def test_compiled_step_names_attention_only_where_it_runs(workload, named):
     names = _compiled_names(workload)
     under = [p for p in names.values() if attention.in_scope(p)]

@@ -1,4 +1,5 @@
-"""Each cell's step compiled at its real size for a described v5e.
+"""Each cell's step compiled at its real size for a described v5e: every
+cell of BENCHMARK.json, and the rehearsal-only four-chip cells.
 
 No chip is needed: the TPU compiler compiles for a topology that is
 described, not attached.  The described topology is made inside a fixture,
@@ -31,7 +32,7 @@ def mosaic(monkeypatch):
     monkeypatch.setattr(kops, "interpret_kernels", lambda: False)
 
 
-@pytest.mark.parametrize("workload", [tiny.SSM_CELL, tiny.DENSE_CELL, tiny.FOUR_CHIP_CELL])
+@pytest.mark.parametrize("workload", tiny.accepted(chips=1) + tiny.cells(chips=4))
 def test_cell_step_compiles_for_v5e_and_fits(topo, mosaic, workload):
     from bench.program import Cell
 
